@@ -1,7 +1,7 @@
 """Benchmark runner — one section per paper table/figure.
 
   paper_runtime_memory : Figs 3-6 (runtime) + Figs 7-10 (memory)
-  scaling              : §4 MapReduce block partitioning (workers sweep)
+  scaling              : §4 MapReduce block partitioning (workers sweep, CPU)
   kernels              : per-kernel micro-latency (CPU ref path)
   service              : cross-group overlap + snapshot warm-start (PR 4)
   roofline             : dry-run aggregation (EXPERIMENTS.md §Roofline)
@@ -69,6 +69,9 @@ def main() -> None:
     ap.add_argument("--skip-scaling", action="store_true")
     args, _ = ap.parse_known_args()
     os.makedirs(RESULTS, exist_ok=True)
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
 
     print("name,us_per_call,derived")
 
@@ -119,7 +122,7 @@ def main() -> None:
                            worlds=(1, 2, 4) if args.quick else (1, 2, 4, 8))
         for r in recs:
             print(
-                f"scaling_workers{r['workers']},{r['warm_s']*1e6:.0f},"
+                f"scaling_cpu_workers{r['workers']},{r['warm_s']*1e6:.0f},"
                 f"shard_nodes={r['max_shard_nodes']}/single={r['total_nodes_single']}"
             )
 

@@ -37,7 +37,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core import encoding as enc
 from repro.fault import failures
 from repro.mining.telemetry import trace
@@ -48,6 +47,13 @@ from repro.kernels.histogram.ops import item_histogram
 from repro.kernels.nlist_intersect.ops import nlist_intersect
 
 INF32 = np.iinfo(np.int32).max
+
+# The mining programs call Pallas kernels inside ``shard_map``. A
+# ``pallas_call`` output carries no varying-axes type, and the Pallas
+# interpreter's discharge cannot track one, so these shard_maps run without
+# the varying-axes check: every collective below sums or maxes genuinely
+# per-shard values.
+shard_map = functools.partial(jax.shard_map, check_vma=False)
 
 # Version tag of the PreparedDB host payload (``to_host``/``from_host``).
 # Bump on any layout change so stale on-disk snapshots are rejected, not
@@ -383,9 +389,12 @@ class HPrepostMiner:
         )
         self.last_stage_times: dict[str, float] = {}
         # how many times each device stage ran over this miner's lifetime —
-        # the engine's shared-prep planning is asserted against these
+        # the engine's shared-prep planning is asserted against these;
+        # ``stop_waves`` counts waves that ran the Pallas early-stop kernel
+        # with a nonzero in-kernel threshold
         self.stage_counters: dict[str, int] = {
-            "job1": 0, "job2": 0, "pack": 0, "f2": 0, "waves": 0
+            "job1": 0, "job2": 0, "pack": 0, "f2": 0, "waves": 0,
+            "stop_waves": 0,
         }
         # KernelPlan resolution: the owning frontend/engine attaches a
         # ``KernelTuner`` here; with ``cfg.tune`` off (or no tuner) plans
@@ -463,8 +472,11 @@ class HPrepostMiner:
             def body(item, count, pre, post):
                 item, count, pre, post = item[0], count[0], pre[0], post[0]
                 n = item.shape[0]
-                # lexsort avoids int32 overflow of a combined item*n+pre key
-                order = jnp.lexsort((jnp.minimum(pre, n), item))
+                # (item, pre) order as two stable one-key sorts: a combined
+                # item*n+pre key would overflow int32, and the TPU compiles a
+                # one-key sort much faster than a multi-key one
+                order = jnp.argsort(jnp.minimum(pre, n), stable=True)
+                order = order[jnp.argsort(item[order], stable=True)]
                 sitem = item[order]
                 boundaries = jnp.searchsorted(sitem, jnp.arange(k + 1))
                 slot = jnp.arange(n) - boundaries[jnp.clip(sitem, 0, k)]
@@ -809,8 +821,9 @@ class HPrepostMiner:
             "mining_waves": 0.0,
             # planning counters ride the stage dict into MineResult
             # stage_times_s: candidates shipped, and candidates the host
-            # bound killed (dead parent / missing Apriori subset)
-            "planned_candidates": 0.0,
+            # bound killed (dead parent / missing Apriori subset), and the
+            # most candidate slots one wave put on the device
+            "planned_candidates": 0.0, "largest_wave": 0.0,
             "host_pruned_parent": 0.0, "host_pruned_subset": 0.0,
         }
         itemsets: dict[tuple[int, ...], int] = {}
@@ -854,6 +867,7 @@ class HPrepostMiner:
         # supports: one data shard (no cross-shard psum completes them
         # later). Off (0) it costs nothing — the mask multiplies by 1.0.
         stop_count = min_count if (cfg.early_stop and self.D == 1) else 0
+        from repro.mining.tune import is_pallas
 
         t0 = time.perf_counter()
         while len(ranks) or pending is not None:
@@ -864,6 +878,7 @@ class HPrepostMiner:
                 )
                 plan = self._kernel_plan(Cpad, prepared.width)
                 stages["planned_candidates"] += float(len(ranks))
+                stages["largest_wave"] = max(stages["largest_wave"], float(Cpad))
                 failures.fire("mine.wave")
                 with trace.span("mine.wave", k=level, candidates=len(ranks)):
                     new_state, sups = wave_fn(
@@ -880,6 +895,8 @@ class HPrepostMiner:
                         early_stop=plan.early_stop,
                     )
                 self.stage_counters["waves"] += 1
+                if stop_count and plan.early_stop and is_pallas(plan.backend):
+                    self.stage_counters["stop_waves"] += 1
                 dispatched = (ranks, parents, slot_of, sups)
                 peak = max(peak, int(new_state.size * 4 // max(self.D * Mb, 1)))
                 prev_state = new_state
@@ -1035,7 +1052,7 @@ class HPrepostMiner:
         stages = self.last_stage_times = {
             "job1_flist": 0.0, "job2_ppc_pack": 0.0, "f2_scan": 0.0,
             "mining_waves": 0.0,
-            "planned_candidates": 0.0,
+            "planned_candidates": 0.0, "largest_wave": 0.0,
             "host_pruned_parent": 0.0, "host_pruned_subset": 0.0,
             "host_pruned_seed": 0.0,
         }
@@ -1092,6 +1109,7 @@ class HPrepostMiner:
                 # stop_count stays 0: per-segment supports are partial until
                 # the cross-segment reduce, so only the host bound prunes here
                 stages["planned_candidates"] += float(len(ranks))
+                stages["largest_wave"] = max(stages["largest_wave"], float(Cpad))
                 with trace.span("mine.wave", k=level, candidates=len(ranks),
                                 segments=executor.n_segments):
                     token = executor.dispatch(

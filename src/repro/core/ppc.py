@@ -122,7 +122,13 @@ def build_ppc_jnp(rows: jnp.ndarray, weights: jnp.ndarray, max_nodes: int, n_ite
         keys = tuple(packed[:, c] for c in range(packed.shape[1] - 1, -1, -1))
     else:
         keys = tuple(rows[:, c] for c in range(L - 1, -1, -1))
-    order = jnp.lexsort(keys)
+    # lexsort as stable one-key sorts, least significant key first: the same
+    # permutation as ``jnp.lexsort(keys)``, but the TPU compiler's time for
+    # one multi-key sort grows steeply with the key count (beyond ten
+    # minutes at 24 keys), while each one-key sort compiles in about a second
+    order = jnp.arange(R)
+    for key in keys:
+        order = order[jnp.argsort(key[order], stable=True)]
     srows = rows[order]
     sw = weights[order]
 

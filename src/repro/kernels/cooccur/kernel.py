@@ -36,7 +36,10 @@ def _cooc_kernel(rows_ref, w_ref, out_ref, *, k_block: int):
     xi = (rows[:, :, None] == bins_i).astype(jnp.float32).sum(axis=1)  # (rb, kb)
     xj = (rows[:, :, None] == bins_j).astype(jnp.float32).sum(axis=1)  # (rb, kb)
     out_ref[...] += jax.lax.dot_general(
-        xi * w, xj, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        xi * w, xj, (((0,), (0,)), ((), ())),
+        # weights above 256 are not exact in one bf16 MXU pass
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
     )
 
 

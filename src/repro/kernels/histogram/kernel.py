@@ -7,8 +7,10 @@ step compares its VMEM-resident row tile against a tile of bin ids and
 reduces on-chip — a pure VPU compare + sum with no scatter (TPUs have no
 fast random scatter; the dense compare is the native form).
 
-Grid: (row_blocks, bin_blocks). The output bin tile is revisited across the
-row-block dimension and accumulated in place (sequential TPU grid).
+Grid: (bin_blocks, row_blocks). The output bin tile accumulates across the
+row-block dimension, which is the innermost grid axis: a TPU keeps an output
+block in VMEM only while consecutive programs revisit it, and never reads it
+back from HBM, so an accumulated block must not be left and revisited.
 """
 from __future__ import annotations
 
@@ -20,7 +22,8 @@ from jax.experimental import pallas as pl
 
 
 def _hist_kernel(rows_ref, weights_ref, out_ref, *, bin_block: int):
-    ri = pl.program_id(0)
+    bi = pl.program_id(0)
+    ri = pl.program_id(1)
 
     @pl.when(ri == 0)
     def _init():
@@ -28,7 +31,6 @@ def _hist_kernel(rows_ref, weights_ref, out_ref, *, bin_block: int):
 
     rows = rows_ref[...]  # (rb, L) int32
     w = weights_ref[...]  # (rb, 1) int32
-    bi = pl.program_id(1)
     bins = bi * bin_block + jax.lax.broadcasted_iota(jnp.int32, (1, 1, bin_block), 2)
     # (rb, L, bin_block) one-hot compare; PAD (-1) never equals a bin id
     onehot = (rows[:, :, None] == bins).astype(jnp.int32)
@@ -57,12 +59,12 @@ def histogram_pallas(
 
     out = pl.pallas_call(
         functools.partial(_hist_kernel, bin_block=bb),
-        grid=(Rp // rb, Bp // bb),
+        grid=(Bp // bb, Rp // rb),
         in_specs=[
-            pl.BlockSpec((rb, L), lambda ri, bi: (ri, 0)),
-            pl.BlockSpec((rb, 1), lambda ri, bi: (ri, 0)),
+            pl.BlockSpec((rb, L), lambda bi, ri: (ri, 0)),
+            pl.BlockSpec((rb, 1), lambda bi, ri: (ri, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bb), lambda ri, bi: (0, bi)),
+        out_specs=pl.BlockSpec((1, bb), lambda bi, ri: (0, bi)),
         out_shape=jax.ShapeDtypeStruct((1, Bp), jnp.int32),
         interpret=interpret,
     )(rows, weights)

@@ -75,6 +75,9 @@ def _intersect_kernel(
         mask.astype(jnp.float32).reshape(bb * la, ly),
         y_cnt,
         (((1,), (1,)), ((), ())),
+        # counts above 256 are not exact in one bf16 MXU pass; the fp32
+        # contraction keeps every count below 2^24 exact
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     ).reshape(bb, la, bb)
     eye = (
@@ -119,9 +122,15 @@ def _intersect_es_kernel(
         sup_ref[...] = jnp.zeros_like(sup_ref)
 
     # (bb, 1): final support <= support so far + A-count mass of tiles i..
-    alive = (sup_ref[...] + rem_ref[...]) >= stop_ref[0, 0]
+    # ``rem_ref`` holds every A-tile's suffix mass (a block narrower than
+    # the full tile axis would break the TPU's (8, 128) tiling); pick this
+    # tile's column with a lane mask, which lowers to plain vector selects
+    rem_all = rem_ref[...]  # (bb, nt)
+    col = jax.lax.broadcasted_iota(jnp.int32, rem_all.shape, 1)
+    rem = jnp.sum(jnp.where(col == lab_i, rem_all, 0.0), axis=1, keepdims=True)
+    alive = (sup_ref[...] + rem) >= stop_ref[0, 0]
 
-    @pl.when(jnp.any(alive))
+    @pl.when(jnp.max(alive.astype(jnp.int32)) > 0)
     def _compute():
         a_pre = a_pre_ref[...]  # (bb, la)
         a_post = a_post_ref[...]
@@ -137,6 +146,7 @@ def _intersect_es_kernel(
             mask.astype(jnp.float32).reshape(bb * la, ly),
             y_cnt,
             (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32,
         ).reshape(bb, la, bb)
         eye = (
@@ -202,7 +212,7 @@ def nlist_intersect_pallas_es(
         grid=(Bp // bb, Lap // lab, Lyp // lyb),
         in_specs=[
             pl.BlockSpec((1, 1), lambda b, i, j: (0, 0)),
-            pl.BlockSpec((bb, 1), lambda b, i, j: (b, i)),
+            pl.BlockSpec((bb, nt), lambda b, i, j: (b, 0)),
             pl.BlockSpec((bb, lab), lambda b, i, j: (b, i)),
             pl.BlockSpec((bb, lab), lambda b, i, j: (b, i)),
             pl.BlockSpec((bb, lyb), lambda b, i, j: (b, j)),

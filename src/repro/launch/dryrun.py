@@ -25,7 +25,6 @@ import traceback
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.configs.base import ARCH_IDS, get_config
 from repro.launch import hlo_analysis as ha
 from repro.launch.mesh import make_mesh_from_spec, make_production_mesh
@@ -94,7 +93,7 @@ def run_cell(arch, shape_name, mesh, mesh_name, seq=None, batch=None, verbose=Tr
     t0 = time.time()
     fn, args = cell_args(cfg, shape_name, mesh, seq=seq, batch=batch)
     arg_bytes_dev = bytes_per_device(args, mesh)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(fn).lower(*args)
         t_lower = time.time() - t0
         compiled = lowered.compile()

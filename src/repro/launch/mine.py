@@ -546,8 +546,10 @@ def main(argv=None):
     if args.expect_obs and not (args.serve and args.stats_interval and args.trace):
         ap.error("--expect-obs needs --serve --stats-interval S --trace FILE")
 
+    from repro.launch.compile_cache import use_compile_cache
     from repro.launch.mesh import make_mesh_from_spec
 
+    use_compile_cache()
     if args.corpus:
         toks = corpus.token_stream(200_000, args.vocab, seed=0)
         rows = corpus.ngram_transactions(toks, window=8, stride=4)

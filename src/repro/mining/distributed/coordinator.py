@@ -77,6 +77,7 @@ class WorkerHandle:
     proc: object
     alive: bool = True
     next_seq: int = 0
+    platform: str = ""  # the JAX platform the worker reported in its hello
 
 
 @dataclasses.dataclass
@@ -293,7 +294,9 @@ class DistributedMiner:
             if hello.get("op") != pr.OP_HELLO:
                 raise pr.ProtocolError(f"expected hello, got {hello!r}")
             wid = int(hello["worker_id"])
-            self._workers[wid] = WorkerHandle(wid=wid, chan=chan, proc=procs[wid])
+            self._workers[wid] = WorkerHandle(
+                wid=wid, chan=chan, proc=procs[wid], platform=hello["platform"]
+            )
 
     def _spawn_workers(self, n: int, spawn_timeout_s: float) -> None:
         self._accept_hellos(self._spawn_procs(list(range(n))), spawn_timeout_s)

@@ -131,7 +131,10 @@ class Worker:
         return {}
 
     def _op_stats(self, msg):
+        import jax
+
         return {
+            "platform": jax.default_backend(),
             "stats": dict(self.stats),
             "segments": sorted(self.segments),
             "bytes": sum(s.nbytes for s in self.segments.values()),
@@ -193,10 +196,18 @@ class Worker:
 def worker_main(address, worker_id: int, n_items: int, spec, row_pad: int,
                 snapshot_dir: str | None) -> None:
     """Process entry point (multiprocessing spawn target): dial the
-    coordinator, introduce ourselves, serve until shutdown or death."""
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    coordinator, introduce ourselves, serve until shutdown or death.
+
+    Workers run on the CPU. The coordinator's own process may hold the
+    accelerator, and an accelerator belongs to one process at a time, so a
+    worker that reached for it would fail or hang."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    import jax
+
+    platform = jax.default_backend()
     chan = dial(tuple(address))
-    chan.send({"op": pr.OP_HELLO, "worker_id": worker_id, "pid": os.getpid()})
+    chan.send({"op": pr.OP_HELLO, "worker_id": worker_id, "pid": os.getpid(),
+               "platform": platform})
     w = Worker(worker_id, n_items=n_items, spec=spec, row_pad=row_pad,
                snapshot_dir=snapshot_dir)
     try:

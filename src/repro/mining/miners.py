@@ -24,7 +24,7 @@ from repro.mining.spec import MineSpec
 @functools.lru_cache(maxsize=1)
 def default_mesh():
     """The 1×1 (data, model) mesh used when no mesh is bound explicitly."""
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
 
     return make_mesh((1, 1), ("data", "model"))
 

@@ -151,8 +151,10 @@ class KernelTuner:
     cartesian search and persists the winner atomically.
     """
 
+    # the TPU lowers a (bb, la) block only when bb is a multiple of 8 (or
+    # the whole batch) and la a multiple of 128 (or the whole width)
     LA_CHOICES = (128, 256, 512)
-    BB_CHOICES = (4, 8, 16)
+    BB_CHOICES = (8, 16)
 
     def __init__(self, plan_dir: str | None = None, platform: str | None = None):
         self._dir = plan_dir
@@ -220,23 +222,29 @@ class KernelTuner:
             self.stats["trials"] += 1
         return best * 1e6
 
-    def _search(self, backend: str, B: int, W: int, early_stop: bool) -> dict:
-        # measure at the bucketed shape (that is what the key promises);
-        # the interpreter is a python loop, so cap its fixture sizes
+    def search_space(self, backend: str, B: int, W: int):
+        """-> (B, W, [(la, bb), ...]): the bucketed fixture shape the search
+        measures at (that is what the key promises) and the block configs it
+        tries there. The interpreter is a python loop, so its fixture sizes
+        are capped."""
         wb = _bucket(W, 8, 1024)
         bbk = _bucket(B, 8, 512)
         if backend == "pallas-interpret":
             wb, bbk = min(wb, 128), min(bbk, 32)
         la_opts = sorted({min(wb, c) for c in self.LA_CHOICES})
         bb_opts = sorted({min(bbk, c) for c in self.BB_CHOICES})
+        return bbk, wb, list(itertools.product(la_opts, bb_opts))
+
+    def _search(self, backend: str, B: int, W: int, early_stop: bool) -> dict:
+        bbk, wb, configs = self.search_space(backend, B, W)
         best = None
-        for la, bb in itertools.product(la_opts, bb_opts):
+        for la, bb in configs:
             us = self._measure_us(backend, bbk, wb, la, la, bb, early_stop)
             if best is None or us < best["best_us"]:
                 best = {
                     "la_block": la, "ly_block": la, "batch_block": bb,
                     "best_us": round(us, 1),
-                    "trials": len(la_opts) * len(bb_opts),
+                    "trials": len(configs),
                 }
         return best
 

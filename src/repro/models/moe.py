@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import get_abstract_mesh, shard_map
 from repro.models.common import ParamSpec
 
 
@@ -44,7 +43,7 @@ def moe_specs(cfg) -> dict:
 
 def _ambient_moe_axes(cfg, batch: int):
     """(data_axes, model_axis) if the ambient mesh supports sharded dispatch."""
-    am = get_abstract_mesh()
+    am = jax.sharding.get_abstract_mesh()
     if am is None or getattr(am, "empty", True):
         return None
     names = getattr(am, "axis_names", ())
@@ -125,7 +124,7 @@ def _moe_sharded(p, x, cfg, data_axes, model_ax, D, M):
         return out.reshape(B_l, S, d), aux
 
     dspec = data_axes if len(data_axes) > 1 else (data_axes[0] if data_axes else None)
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         body,
         in_specs=(P(dspec, None, None), P(), P("model"), P("model"), P("model")),
         out_specs=(P(dspec, None, None), P()),

@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import pcast, shard_map
 
 
 def gpipe_forward(
@@ -53,8 +52,8 @@ def gpipe_forward(
 
         n_ticks = n_micro + S - 1
         # initial carries must already be device-varying for the scan
-        buf = pcast(jnp.zeros_like(xs_local[0]), (axis,), to="varying")
-        outs = pcast(jnp.zeros_like(xs_local), (axis,), to="varying")
+        buf = jax.lax.pcast(jnp.zeros_like(xs_local[0]), (axis,), to="varying")
+        outs = jax.lax.pcast(jnp.zeros_like(xs_local), (axis,), to="varying")
 
         def tick(carry, t):
             buf, outs = carry
@@ -82,7 +81,7 @@ def gpipe_forward(
         return outs
 
     pspec = jax.tree.map(lambda _: P(axis), stacked_params)
-    return shard_map(
+    return jax.shard_map(
         stage_body,
         mesh=mesh,
         in_specs=(pspec, P()),

@@ -126,6 +126,14 @@ def test_segments_spread_over_both_workers(cluster):
     assert owners == {0, 1}  # byte-balanced placement used the whole pool
 
 
+def test_workers_report_cpu_platform(cluster):
+    """Workers run on the CPU (the coordinator's process may hold the
+    accelerator) and say so in their hello and in their stats."""
+    _, dm, _, _ = cluster
+    assert {w.platform for w in dm._workers.values()} == {"cpu"}
+    assert {s["platform"] for s in dm.worker_stats().values()} == {"cpu"}
+
+
 def test_distributed_through_service_future_path(cluster):
     eng, dm, batches, n_items = cluster
     svc = MiningService(engine=eng)

@@ -22,7 +22,7 @@ from repro.data.synth import random_db
 @pytest.fixture(scope="module")
 def mesh11():
     import jax
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
 
     return make_mesh((1, 1), ("data", "model"))
 
@@ -61,7 +61,7 @@ _SUBPROC = textwrap.dedent(
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import numpy as np, jax
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.core.hprepost import HPrepostMiner, HPrepostConfig
     from repro.core.prepost import mine_prepost
     from repro.data.synth import random_db

@@ -136,3 +136,46 @@ def test_histogram_dtype_and_shape_edge(dtype):
     rows = jnp.zeros((1, 1), dtype)
     got = histogram_pallas(rows, jnp.ones(1, jnp.int32), n_bins=1, interpret=True)
     assert int(got[0]) == 1
+
+
+@pytest.mark.parametrize("kernel", ["histogram", "cooccur", "intersect", "intersect_es"])
+def test_kernels_under_tpu_interpreter(kernel):
+    """The TPU interpreter models what ``interpret=True`` cannot: an output
+    block lives in VMEM only while consecutive grid programs revisit it and
+    is never read back. Grids of several blocks on every axis catch a
+    kernel that accumulates along a grid axis other than the innermost."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from repro.kernels.nlist_intersect.kernel import nlist_intersect_pallas_es
+    from repro.kernels.nlist_intersect.ref import nlist_intersect_masked_ref
+
+    tpu = pltpu.InterpretParams()
+    rng = np.random.default_rng(7)
+    if kernel in ("histogram", "cooccur"):
+        K = 300 if kernel == "histogram" else 130
+        rows = jnp.asarray(rng.integers(-1, K, size=(200, 6)).astype(np.int32))
+        w = jnp.asarray(rng.integers(1, 4, size=200).astype(np.int32))
+        if kernel == "histogram":
+            got = histogram_pallas(rows, w, n_bins=K, row_block=64, bin_block=128,
+                                   interpret=tpu)
+            want = histogram_ref(rows, w, n_bins=K)
+        else:
+            got = cooccur_pallas(rows, w, n_items=K, row_block=64, k_block=64,
+                                 interpret=tpu)
+            want = cooccur_ref(rows, w, n_items=K)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        return
+    a_pre, a_post, y_pre, y_post, y_cnt = _nlist_batch(rng, 16, 200, 200)
+    blocks = dict(la_block=64, ly_block=64, batch_block=8, interpret=tpu)
+    if kernel == "intersect":
+        got = nlist_intersect_pallas(a_pre, a_post, y_pre, y_post, y_cnt, **blocks)
+        want = nlist_intersect_ref(a_pre, a_post, y_pre, y_post, y_cnt)
+        want = (want, want.sum(axis=1))
+    else:
+        a_cnt = jnp.where(a_post >= 0, 3, 0).astype(jnp.int32)
+        got = nlist_intersect_pallas_es(a_pre, a_post, a_cnt, y_pre, y_post, y_cnt,
+                                        12, **blocks)
+        want = nlist_intersect_masked_ref(a_pre, a_post, a_cnt, y_pre, y_post, y_cnt,
+                                          12, la_block=64)
+    for g, w_ in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w_))

@@ -67,14 +67,14 @@ def test_moe_sharded_equals_dense():
     from repro.configs.base import get_config
     from repro.models.common import init_params
     from repro.models.moe import _moe_dense, moe_ffn, moe_specs
-    from repro.compat import make_mesh, set_mesh
+    from repro.launch.mesh import make_mesh
 
     cfg = dataclasses.replace(get_config("granite_moe").reduced(), capacity_factor=4.0)
     p = init_params(moe_specs(cfg), jax.random.PRNGKey(0))
     x = jax.random.normal(jax.random.PRNGKey(1), (4, 8, cfg.d_model), jnp.float32)
     out_d, aux_d = jax.jit(lambda p, x: _moe_dense(p, x, cfg))(p, x)
     mesh = make_mesh((1, 1), ("data", "model"))
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         out_s, aux_s = jax.jit(lambda p, x: moe_ffn(p, x, cfg))(p, x)
     np.testing.assert_allclose(np.asarray(out_d), np.asarray(out_s), rtol=2e-5, atol=2e-5)
     np.testing.assert_allclose(float(aux_d), float(aux_s), rtol=1e-5)

@@ -9,7 +9,7 @@ _SUBPROC = textwrap.dedent(
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import jax, jax.numpy as jnp, numpy as np
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.training.pipeline import gpipe_forward
 
     mesh = make_mesh((4,), ("pipe",))

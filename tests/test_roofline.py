@@ -39,7 +39,7 @@ def test_nested_scan_multiplies():
 def test_collectives_inside_scan_multiplied():
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import make_mesh, shard_map
+    from repro.launch.mesh import make_mesh
 
     mesh = make_mesh((1,), ("data",))
 
@@ -48,7 +48,7 @@ def test_collectives_inside_scan_multiplied():
             def body(c, w):
                 return jax.lax.psum(c @ w, "data"), None
             return jax.lax.scan(body, x, ws)[0]
-        return shard_map(inner, mesh=mesh, in_specs=(P(), P()), out_specs=P())(x, ws)
+        return jax.shard_map(inner, mesh=mesh, in_specs=(P(), P()), out_specs=P())(x, ws)
 
     x = jax.ShapeDtypeStruct((128, 128), jnp.float32)
     ws = jax.ShapeDtypeStruct((6, 128, 128), jnp.float32)
@@ -80,12 +80,12 @@ def test_collective_bytes_text_parser_agrees():
     """The simple text parser (used for reference) sees the same op types."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import make_mesh, shard_map
+    from repro.launch.mesh import make_mesh
 
     mesh = make_mesh((1,), ("data",))
 
     def f(x):
-        return shard_map(
+        return jax.shard_map(
             lambda x: jax.lax.psum(x, "data"),
             mesh=mesh, in_specs=P("data", None), out_specs=P(),
         )(x)

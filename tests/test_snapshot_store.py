@@ -254,7 +254,7 @@ def test_cross_shard_count_warm_start_where_mesh_allows(tmp_path):
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
         import numpy as np
-        from repro.compat import make_mesh
+        from repro.launch.mesh import make_mesh
         from repro.data.synth import random_db
         from repro.mining import MineSpec, MiningEngine
 

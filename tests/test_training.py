@@ -152,7 +152,7 @@ def test_checkpoint_elastic_reshard(tmp_path):
     """Save on one 'mesh', restore with different shardings (elasticity)."""
     from repro.checkpoint.ckpt import CheckpointManager
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
 
     cm = CheckpointManager(str(tmp_path))
     state = {"w": jnp.arange(64, dtype=jnp.float32).reshape(8, 8)}

@@ -134,3 +134,18 @@ def test_tuner_keys_split_by_backend_shape_and_early_stop():
     assert t._key("pallas-interpret", 100, 300, True) != k
     # same bucket -> same key (the memoization grain)
     assert t._key("jnp", 65, 257, True) == k
+
+
+@pytest.mark.parametrize("backend", ["pallas-tpu", "pallas-interpret", "jnp"])
+def test_tuner_search_space_only_tpu_lowerable_blocks(backend):
+    """Every (la, bb) the search would time is a block the TPU lowers:
+    bb a multiple of 8 or the whole fixture batch, la a multiple of 128
+    or the whole fixture width."""
+    t = KernelTuner(platform="tpu")
+    for B in (1, 3, 8, 9, 100, 512, 5000):
+        for W in (1, 8, 64, 128, 300, 1024, 16384):
+            bbk, wb, configs = t.search_space(backend, B, W)
+            assert configs
+            for la, bb in configs:
+                assert bb % 8 == 0 or bb == bbk, (B, W, la, bb)
+                assert la % 128 == 0 or la == wb, (B, W, la, bb)
