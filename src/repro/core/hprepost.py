@@ -607,35 +607,37 @@ class HPrepostMiner:
         cfg = self.cfg
         stages: dict[str, float] = {}
         t0 = time.perf_counter()
-        R0, L = rows.shape
-        Rp = (R0 + self.D - 1) // self.D * self.D
-        # the Pallas intersect kernel accumulates counts in fp32 (exact only
-        # below 2^24); every count it can produce is bounded by the shard's
-        # transaction count, so refuse shards that could silently wrap. The
-        # jnp path is integer-exact — only the Pallas dispatch is guarded.
-        from repro.kernels.nlist_intersect.ops import FP32_EXACT_MAX
-        from repro.mining.tune import is_pallas, resolve_backend
+        with trace.span("prep.job1"):
+            R0, L = rows.shape
+            Rp = (R0 + self.D - 1) // self.D * self.D
+            # the Pallas intersect kernel accumulates counts in fp32 (exact
+            # only below 2^24); every count it can produce is bounded by the
+            # shard's transaction count, so refuse shards that could silently
+            # wrap. The jnp path is integer-exact — only the Pallas dispatch
+            # is guarded.
+            from repro.kernels.nlist_intersect.ops import FP32_EXACT_MAX
+            from repro.mining.tune import is_pallas, resolve_backend
 
-        if is_pallas(resolve_backend(cfg.backend)) and Rp // self.D >= FP32_EXACT_MAX:
-            raise ValueError(
-                f"per-shard row count {Rp // self.D} reaches the fp32 exact-"
-                f"integer bound 2^24; shard the database over more devices "
-                f"(D={self.D}) so N-list counts stay exactly representable"
-            )
-        rows_p = np.full((Rp, L), enc.PAD, np.int32)
-        rows_p[:R0] = rows
-        rows_sharded = self._shard(rows_p, P(self._da, None))
-
-        if flist is None:
-            supports = np.asarray(jax.device_get(self._job1(rows_sharded, n_items=n_items)))
-            self.stage_counters["job1"] += 1
-            fl = enc.build_flist(supports, min_count_floor)
-        else:
-            if flist.n_items != n_items:
+            if is_pallas(resolve_backend(cfg.backend)) and Rp // self.D >= FP32_EXACT_MAX:
                 raise ValueError(
-                    f"imposed flist covers {flist.n_items} items, database has {n_items}"
+                    f"per-shard row count {Rp // self.D} reaches the fp32 exact-"
+                    f"integer bound 2^24; shard the database over more devices "
+                    f"(D={self.D}) so N-list counts stay exactly representable"
                 )
-            fl = flist
+            rows_p = np.full((Rp, L), enc.PAD, np.int32)
+            rows_p[:R0] = rows
+            rows_sharded = self._shard(rows_p, P(self._da, None))
+
+            if flist is None:
+                supports = np.asarray(jax.device_get(self._job1(rows_sharded, n_items=n_items)))
+                self.stage_counters["job1"] += 1
+                fl = enc.build_flist(supports, min_count_floor)
+            else:
+                if flist.n_items != n_items:
+                    raise ValueError(
+                        f"imposed flist covers {flist.n_items} items, database has {n_items}"
+                    )
+                fl = flist
         stages["job1_flist"] = time.perf_counter() - t0
         K = fl.k
         if K > cfg.max_f1:
@@ -650,23 +652,30 @@ class HPrepostMiner:
         C = np.zeros((K, K), np.int64)
         W = 0
         if K > 0 and need_waves:
+            # each stage span ends when its device work is done: Job 2 at
+            # the device_get of its N-list lengths, pack at an explicit
+            # sync, F2 at its device_get
             t0 = time.perf_counter()
-            max_nodes = (Rp // self.D) * L
-            ranked, item, count, pre, post, lens = self._job2(
-                rows_sharded, jnp.asarray(fl.rank_lut()), max_nodes=max_nodes, k=K, n_items=n_items
-            )
-            self.stage_counters["job2"] += 1
-            w_needed = int(np.asarray(jax.device_get(lens)).max(initial=1))
+            with trace.span("prep.job2"):
+                max_nodes = (Rp // self.D) * L
+                ranked, item, count, pre, post, lens = self._job2(
+                    rows_sharded, jnp.asarray(fl.rank_lut()), max_nodes=max_nodes, k=K,
+                    n_items=n_items,
+                )
+                self.stage_counters["job2"] += 1
+                w_needed = int(np.asarray(jax.device_get(lens)).max(initial=1))
             W = cfg.nlist_width or _pow2(max(w_needed, 8))
-            packed = self._pack(item, count, pre, post, k=K, width=W)
-            self.stage_counters["pack"] += 1
+            with trace.span("prep.pack"):
+                packed = jax.block_until_ready(self._pack(item, count, pre, post, k=K, width=W))
+                self.stage_counters["pack"] += 1
             stages["job2_ppc_pack"] = time.perf_counter() - t0
 
             t0 = time.perf_counter()
-            if K > 1:
-                C = np.asarray(jax.device_get(self._jobf2(ranked, k=K)))
-                self.stage_counters["f2"] += 1
-            C = np.triu(C, 1)
+            with trace.span("prep.f2"):
+                if K > 1:
+                    C = np.asarray(jax.device_get(self._jobf2(ranked, k=K)))
+                    self.stage_counters["f2"] += 1
+                C = np.triu(C, 1)
             stages["f2_scan"] = time.perf_counter() - t0
             prep_bytes += int(packed.size * 4 // max(self.D, 1))
             # level-2 bootstrap: parents are singletons, prev_state = node
@@ -821,9 +830,10 @@ class HPrepostMiner:
             "mining_waves": 0.0,
             # planning counters ride the stage dict into MineResult
             # stage_times_s: candidates shipped, and candidates the host
-            # bound killed (dead parent / missing Apriori subset), and the
-            # most candidate slots one wave put on the device
-            "planned_candidates": 0.0, "largest_wave": 0.0,
+            # bound killed (dead parent / missing Apriori subset), the most
+            # candidate slots one wave put on the device, and the padded
+            # slots of all waves (what the kernel computes)
+            "planned_candidates": 0.0, "largest_wave": 0.0, "wave_slots": 0.0,
             "host_pruned_parent": 0.0, "host_pruned_subset": 0.0,
         }
         itemsets: dict[tuple[int, ...], int] = {}
@@ -879,6 +889,7 @@ class HPrepostMiner:
                 plan = self._kernel_plan(Cpad, prepared.width)
                 stages["planned_candidates"] += float(len(ranks))
                 stages["largest_wave"] = max(stages["largest_wave"], float(Cpad))
+                stages["wave_slots"] += float(Cpad)
                 failures.fire("mine.wave")
                 with trace.span("mine.wave", k=level, candidates=len(ranks)):
                     new_state, sups = wave_fn(
@@ -1052,7 +1063,7 @@ class HPrepostMiner:
         stages = self.last_stage_times = {
             "job1_flist": 0.0, "job2_ppc_pack": 0.0, "f2_scan": 0.0,
             "mining_waves": 0.0,
-            "planned_candidates": 0.0, "largest_wave": 0.0,
+            "planned_candidates": 0.0, "largest_wave": 0.0, "wave_slots": 0.0,
             "host_pruned_parent": 0.0, "host_pruned_subset": 0.0,
             "host_pruned_seed": 0.0,
         }
@@ -1110,6 +1121,7 @@ class HPrepostMiner:
                 # the cross-segment reduce, so only the host bound prunes here
                 stages["planned_candidates"] += float(len(ranks))
                 stages["largest_wave"] = max(stages["largest_wave"], float(Cpad))
+                stages["wave_slots"] += float(Cpad)
                 with trace.span("mine.wave", k=level, candidates=len(ranks),
                                 segments=executor.n_segments):
                     token = executor.dispatch(

@@ -94,6 +94,10 @@ class MineRequest:
     # scheduler/engine spans parent into the request's tree. Like QoS
     # fields, never part of any plan/prep/snapshot key.
     trace_id: int | None = None
+    # ``time.monotonic()`` instant at which this request's own answer was
+    # complete, stamped by whoever served it; the service reports the gap
+    # to its Future resolving as ``service_stats["hold_s"]``.
+    answered_at: float | None = None
 
 
 class MiningEngine:
@@ -686,6 +690,7 @@ class MiningEngine:
                 prep_shared=not payer,
                 t0=time.perf_counter() - prep_s if payer else None,
             )
+            r.answered_at = time.monotonic()
             res.service_stats["prep_source"] = source
             self._observe_result(res)
             out.append(res)

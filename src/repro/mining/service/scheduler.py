@@ -253,7 +253,9 @@ class GroupScheduler:
         try:
             with trace.span("host.mine", parent=r.trace_id,
                             algorithm=r.spec.algorithm):
-                return self.engine.submit(r.rows, r.n_items, r.spec)
+                res = self.engine.submit(r.rows, r.n_items, r.spec)
+            r.answered_at = time.monotonic()
+            return res
         except Exception as e:
             return e
         finally:

@@ -30,8 +30,11 @@ with a result or a typed error, whatever fails:
     are failed with ``ServiceClosed`` — no orphaned Futures, ever.
 
 Telemetry rides each ``MineResult.service_stats``: queue time, batch
-size, where the prep came from (built / LRU cache / snapshot) and whether
-it overlapped an earlier group's mining. ``stats`` stays the historical
+size, the hold (from the request's own answer being complete to its
+Future resolving, which includes serving the rest of its batch), where
+the prep came from (built / LRU cache / snapshot) and whether it
+overlapped an earlier group's mining. An append's telemetry dict gets the
+same queue time, batch size and hold. ``stats`` stays the historical
 counter dict *and* is callable: ``service.stats()`` returns the full
 operator snapshot (admission/shed/deadline/retry/respawn counters,
 scheduler + engine + per-stream distributed stats). ``drain()`` blocks
@@ -177,6 +180,7 @@ class MiningService:
         shed: list[_Pending] = []
         admitted = False
         enq_err: BaseException | None = None
+        rec = trace.active()
         with self._cv:
             if self._closed or self._worker_dead:
                 raise ServiceClosed("MiningService is closed")
@@ -185,19 +189,21 @@ class MiningService:
             except BaseException as e:
                 enq_err = e
             else:
+                if rec is not None:
+                    # the request's root span: opened at submit time, before
+                    # the worker can see the request, and closed when its
+                    # Future resolves in _serve (or on a crashed batch)
+                    p.trace_id = rec.open(
+                        "request", t0=p.submitted_at, kind=p.kind, priority=p.priority
+                    )
+                    if p.req is not None:
+                        p.req.trace_id = p.trace_id
                 admitted, shed = self._q.offer(p)
                 if admitted:
                     self._outstanding += 1
                     self.stats["requests"] += 1
-        rec = trace.active()
-        if admitted and rec is not None:
-            # the request's root span: opened at submit time, closed when
-            # its Future resolves in _serve (or on a crashed batch)
-            p.trace_id = rec.open(
-                "request", t0=p.submitted_at, kind=p.kind, priority=p.priority
-            )
-            if p.req is not None:
-                p.req.trace_id = p.trace_id
+        if rec is not None and p.trace_id is not None and not admitted:
+            rec.close(p.trace_id, error="rejected")
         # resolve losers outside the lock (their callbacks run inline)
         for s in shed:
             if rec is not None and s.trace_id is not None:
@@ -398,18 +404,19 @@ class MiningService:
         if first is None:
             return [], True
         batch = [first]
-        deadline = time.monotonic() + self.batch_window_s
-        while True:
-            timeout = deadline - time.monotonic()
-            if timeout <= 0:
-                return batch, False
-            try:
-                item = self._q.get(timeout=timeout)
-            except queue.Empty:
-                return batch, False
-            if item is None:
-                return batch, True
-            batch.append(item)
+        with trace.span("service.batch_window", parent=first.trace_id):
+            deadline = time.monotonic() + self.batch_window_s
+            while True:
+                timeout = deadline - time.monotonic()
+                if timeout <= 0:
+                    return batch, False
+                try:
+                    item = self._q.get(timeout=timeout)
+                except queue.Empty:
+                    return batch, False
+                if item is None:
+                    return batch, True
+                batch.append(item)
 
     def _fail_batch(self, batch: list[_Pending], exc: BaseException) -> None:
         """Resolve every unresolved Future in a crashed batch with the
@@ -463,6 +470,9 @@ class MiningService:
         # (appends / stream queries) run inline between them — a query that
         # arrived after an append must observe the appended segment
         results: list = [None] * len(batch)
+        # when each operation's own answer was complete: a mining request's
+        # is stamped on its req by whoever served it, a stream op's here
+        answered: list = [None] * len(batch)
         chunk: list[int] = []
 
         def flush_chunk():
@@ -476,6 +486,7 @@ class MiningService:
                 out = [e] * len(chunk)
             for j, r in zip(chunk, out):
                 results[j] = r
+                answered[j] = batch[j].req.answered_at
             chunk.clear()
 
         for i, p in enumerate(batch):
@@ -492,19 +503,26 @@ class MiningService:
             try:
                 with trace.span("stream.op", parent=p.trace_id):
                     results[i] = p.run()
+                answered[i] = time.monotonic()
             except BaseException as e:
                 results[i] = e
         flush_chunk()
         req_hist = self.engine.telemetry.histogram("service.request_s")
-        for p, res in zip(batch, results):
+        for p, res, t_answer in zip(batch, results, answered):
             t_res = time.monotonic()
             if isinstance(res, BaseException):
                 p.future.set_exception(res)
             else:
-                if isinstance(res, MineResult):
-                    res.service_stats.update(
+                # a MineResult's service_stats, or an append's telemetry dict
+                stats = res.service_stats if isinstance(res, MineResult) else res
+                if isinstance(stats, dict):
+                    stats.update(
                         queue_time_s=t_start - p.submitted_at, batch_size=len(batch)
                     )
+                    if t_answer is not None:
+                        # answer complete -> Future resolved: the wait for
+                        # the rest of the batch
+                        stats["hold_s"] = t_res - t_answer
                 p.future.set_result(res)
             now = time.monotonic()
             req_hist.record(now - p.submitted_at)

@@ -9,13 +9,26 @@ CLI does this for ``--trace out.json``) and the same sites produce a
 span tree per request:
 
     request                      (opened at submit, closed at resolve)
+      service.batch_window       (the first request of a batch: its arrival
+                                  -> the window closing)
       admission.wait             (retroactive: submit -> batch start)
       group.classify
       group.prep
       group.serve
         mine.wave k=2            (device dispatch, per level)
-        mine.reduce k=2          (host blocking collect + prune)
-      resolve
+        mine.reduce k=2          (the blocking device_get of a wave's
+                                  supports, summed over a stream's
+                                  segments; the host prune is outside)
+      resolve                    (retroactive)
+
+    prep.job1                    (padding, device_put, Job 1 to its device_get)
+    prep.job2                    (Job 2 to the device_get of its N-list lengths)
+    prep.pack                    (pack to its block_until_ready)
+    prep.f2                      (F2 to its device_get)
+
+The ``prep.*`` spans nest under the span open on the thread that
+prepares (``host.mine``, ``group.prep``, ``stream.append``); on the
+scheduler's prep thread, where a group's prep runs ahead, they are roots.
 
 Parenting is two-mode: explicit (``parent=`` span id, used across
 threads — the service carries the request root's id on its ``_Pending``
@@ -216,10 +229,3 @@ class TraceRecorder:
             json.dump(events, f, indent=1)
             f.write("\n")
         return len(events)
-
-    def save_json(self, path: str) -> int:
-        roots = self.to_json()
-        with open(path, "w") as f:
-            json.dump(roots, f, indent=1)
-            f.write("\n")
-        return len(roots)

@@ -370,3 +370,70 @@ def test_distributed_mine_records_per_worker_wave_histograms():
         assert hs["dist.t.query_s"]["count"] == 1
     finally:
         dm.close()
+
+
+# ------------------------------------------ prep stages, batching, hold
+PREP_SPANS = ["prep.job1", "prep.job2", "prep.pack", "prep.f2"]
+
+
+def test_prepare_records_stage_spans_that_sum_to_the_stage_times():
+    eng = MiningEngine()
+    rows = random_db(np.random.default_rng(4), 120, 10, 6)
+    spec = MineSpec(algorithm="hprepost", max_k=4, candidate_unit=8, min_sup=0.3)
+    rec = TraceRecorder()
+    with trace.attached(rec):
+        res = eng.submit(rows, 10, spec)
+    prep = sorted((s for s in rec.spans.values() if s["name"].startswith("prep.")),
+                  key=lambda s: s["t0"])
+    assert [s["name"] for s in prep] == PREP_SPANS
+    span_s = sum(s["t1"] - s["t0"] for s in prep)
+    stage_s = sum(res.stage_times_s[k] for k in ("job1_flist", "job2_ppc_pack", "f2_scan"))
+    assert span_s == pytest.approx(stage_s, rel=0.05)
+
+
+def test_service_batch_window_span_and_append_queue_time():
+    from repro.mining.service import MiningService
+
+    rng = np.random.default_rng(6)
+    spec = MineSpec(algorithm="hprepost", max_k=4, candidate_unit=8, min_sup=0.3)
+    rec = TraceRecorder()
+    with MiningService(batch_window_s=0.02) as svc, trace.attached(rec):
+        out = svc.append(random_db(rng, 40, 10, 6), 10, spec=spec).result(timeout=120)
+        svc.drain()
+        batches = svc.stats["batches"]
+    assert out["queue_time_s"] >= 0 and out["hold_s"] >= 0 and out["batch_size"] == 1
+    windows = [s for s in rec.spans.values() if s["name"] == "service.batch_window"]
+    assert batches == 1 and len(windows) == 1
+    (root,) = [sid for sid, s in rec.spans.items() if s["name"] == "request"]
+    assert windows[0]["parent"] == root
+    assert windows[0]["t1"] - windows[0]["t0"] >= 0.02 * 0.9
+
+
+def test_sweep_results_carry_their_resolve_hold():
+    from repro.mining.service import MiningService
+
+    rows = random_db(np.random.default_rng(8), 160, 10, 6)
+    spec = MineSpec(algorithm="hprepost", max_k=4, candidate_unit=8)
+    with MiningService(batch_window_s=0.2) as svc:
+        results = [f.result(timeout=120)
+                   for f in svc.sweep(rows, 10, spec, [0.4, 0.3, 0.2, 0.15])]
+    assert {r.service_stats["batch_size"] for r in results} == {4}
+    holds = [r.service_stats["hold_s"] for r in results]
+    assert all(h >= 0 for h in holds)
+    # every answer waits for the thresholds served after it
+    assert holds[0] > holds[-1]
+
+
+def test_wave_slots_count_the_padded_slots_of_every_wave():
+    eng = MiningEngine()
+    rng = np.random.default_rng(9)
+    spec = MineSpec(algorithm="hprepost", max_k=4, candidate_unit=8, min_sup=0.05)
+    res = eng.submit(random_db(rng, 120, 10, 6), 10, spec)
+    for _ in range(2):
+        eng.append(random_db(rng, 50, 10, 6), 10, spec=spec)
+    query = eng.submit_stream(spec)
+    for r in (res, query):
+        st = r.stage_times_s
+        assert any(len(s) >= 2 for s in r.itemsets)  # waves really ran
+        assert st["wave_slots"] >= st["planned_candidates"] > 0
+        assert st["wave_slots"] >= st["largest_wave"]
