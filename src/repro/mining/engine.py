@@ -94,10 +94,6 @@ class MineRequest:
     # scheduler/engine spans parent into the request's tree. Like QoS
     # fields, never part of any plan/prep/snapshot key.
     trace_id: int | None = None
-    # ``time.monotonic()`` instant at which this request's own answer was
-    # complete, stamped by whoever served it; the service reports the gap
-    # to its Future resolving as ``service_stats["hold_s"]``.
-    answered_at: float | None = None
 
 
 class MiningEngine:
@@ -210,7 +206,10 @@ class MiningEngine:
 
     def _observe_result(self, res: MineResult) -> None:
         """Record one answered request into the latency registry. Totals
-        stay in ``stats``/``cache_info``; these are the distributions."""
+        stay in ``stats``/``cache_info``; these are the distributions.
+        Also stamps ``service_stats["answered_at"]``, the instant this
+        answer was complete, which the service turns into ``hold_s``."""
+        res.service_stats["answered_at"] = time.monotonic()
         t = self.telemetry
         t.histogram("engine.mine_s").record(res.wall_time_s)
         for k in _STAGE_KEYS:
@@ -690,7 +689,6 @@ class MiningEngine:
                 prep_shared=not payer,
                 t0=time.perf_counter() - prep_s if payer else None,
             )
-            r.answered_at = time.monotonic()
             res.service_stats["prep_source"] = source
             self._observe_result(res)
             out.append(res)

@@ -44,8 +44,12 @@ class MineResult:
     #   prep_source      "built" | "cache" | "snapshot" (engine)
     #   prep_overlapped  True when this group's prepare ran while an earlier
     #                    group was still mining (scheduler)
+    #   answered_at      ``time.monotonic()`` when the answer was complete
+    #                    (engine; the service replaces it with hold_s)
     #   queue_time_s     submit -> batch-execution-start (service)
     #   batch_size       requests coalesced into this request's batch (service)
+    #   hold_s           answer complete -> Future resolved, the wait for the
+    #                    rest of the batch (service)
     service_stats: dict = dataclasses.field(default_factory=dict)
 
     def support_of(self, itemset) -> int:

@@ -253,9 +253,7 @@ class GroupScheduler:
         try:
             with trace.span("host.mine", parent=r.trace_id,
                             algorithm=r.spec.algorithm):
-                res = self.engine.submit(r.rows, r.n_items, r.spec)
-            r.answered_at = time.monotonic()
-            return res
+                return self.engine.submit(r.rows, r.n_items, r.spec)
         except Exception as e:
             return e
         finally:

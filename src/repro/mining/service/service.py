@@ -34,7 +34,7 @@ size, the hold (from the request's own answer being complete to its
 Future resolving, which includes serving the rest of its batch), where
 the prep came from (built / LRU cache / snapshot) and whether it
 overlapped an earlier group's mining. An append's telemetry dict gets the
-same queue time, batch size and hold. ``stats`` stays the historical
+queue time. ``stats`` stays the historical
 counter dict *and* is callable: ``service.stats()`` returns the full
 operator snapshot (admission/shed/deadline/retry/respawn counters,
 scheduler + engine + per-stream distributed stats). ``drain()`` blocks
@@ -74,7 +74,7 @@ class _Pending:                   # and field-wise eq chokes on array payloads
     req: MineRequest | None  # None for stream operations
     future: Future
     submitted_at: float
-    kind: str = "mine"  # "mine" | "stream" (append / stream query)
+    kind: str = "mine"  # "mine" | "append" | "stream" (other stream ops)
     run: object = None  # stream ops: zero-arg callable executed in order
     deadline_at: float | None = None  # monotonic instant; admission + QoS
     priority: int = 0
@@ -159,13 +159,13 @@ class MiningService:
         return [self.submit(r.rows, r.n_items, r.spec) for r in requests]
 
     def _submit_stream_op(self, run, *, spec: MineSpec | None = None,
-                          nbytes: int = 0) -> Future:
+                          nbytes: int = 0, kind: str = "stream") -> Future:
         deadline_at = (
             time.monotonic() + spec.deadline_s
             if spec is not None and spec.deadline_s is not None else None
         )
         return self._enqueue(_Pending(
-            None, Future(), time.monotonic(), kind="stream", run=run,
+            None, Future(), time.monotonic(), kind=kind, run=run,
             deadline_at=deadline_at,
             priority=spec.priority if spec is not None else 0,
             nbytes=int(nbytes),
@@ -240,7 +240,7 @@ class MiningService:
             lambda: self.engine.append(
                 rows, n_items, stream=stream, spec=spec, stream_spec=stream_spec
             ),
-            nbytes=rows.nbytes,
+            nbytes=rows.nbytes, kind="append",
         )
 
     def submit_stream(self, spec: MineSpec, *, stream: str = "default") -> Future:
@@ -470,9 +470,6 @@ class MiningService:
         # (appends / stream queries) run inline between them — a query that
         # arrived after an append must observe the appended segment
         results: list = [None] * len(batch)
-        # when each operation's own answer was complete: a mining request's
-        # is stamped on its req by whoever served it, a stream op's here
-        answered: list = [None] * len(batch)
         chunk: list[int] = []
 
         def flush_chunk():
@@ -486,7 +483,6 @@ class MiningService:
                 out = [e] * len(chunk)
             for j, r in zip(chunk, out):
                 results[j] = r
-                answered[j] = batch[j].req.answered_at
             chunk.clear()
 
         for i, p in enumerate(batch):
@@ -503,26 +499,30 @@ class MiningService:
             try:
                 with trace.span("stream.op", parent=p.trace_id):
                     results[i] = p.run()
-                answered[i] = time.monotonic()
             except BaseException as e:
                 results[i] = e
+            else:
+                if isinstance(results[i], MineResult):  # a stream query
+                    results[i].service_stats["answered_at"] = time.monotonic()
         flush_chunk()
         req_hist = self.engine.telemetry.histogram("service.request_s")
-        for p, res, t_answer in zip(batch, results, answered):
+        for p, res in zip(batch, results):
             t_res = time.monotonic()
             if isinstance(res, BaseException):
                 p.future.set_exception(res)
             else:
-                # a MineResult's service_stats, or an append's telemetry dict
-                stats = res.service_stats if isinstance(res, MineResult) else res
-                if isinstance(stats, dict):
-                    stats.update(
+                if isinstance(res, MineResult):
+                    st = res.service_stats
+                    st.update(
                         queue_time_s=t_start - p.submitted_at, batch_size=len(batch)
                     )
+                    t_answer = st.pop("answered_at", None)
                     if t_answer is not None:
                         # answer complete -> Future resolved: the wait for
                         # the rest of the batch
-                        stats["hold_s"] = t_res - t_answer
+                        st["hold_s"] = t_res - t_answer
+                elif p.kind == "append":
+                    res["queue_time_s"] = t_start - p.submitted_at
                 p.future.set_result(res)
             now = time.monotonic()
             req_hist.record(now - p.submitted_at)

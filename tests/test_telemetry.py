@@ -401,7 +401,9 @@ def test_service_batch_window_span_and_append_queue_time():
         out = svc.append(random_db(rng, 40, 10, 6), 10, spec=spec).result(timeout=120)
         svc.drain()
         batches = svc.stats["batches"]
-    assert out["queue_time_s"] >= 0 and out["hold_s"] >= 0 and out["batch_size"] == 1
+    # an append's answer gains the queue time and no other serving telemetry
+    assert out["queue_time_s"] >= 0
+    assert "hold_s" not in out and "batch_size" not in out
     windows = [s for s in rec.spans.values() if s["name"] == "service.batch_window"]
     assert batches == 1 and len(windows) == 1
     (root,) = [sid for sid, s in rec.spans.items() if s["name"] == "request"]
@@ -422,6 +424,26 @@ def test_sweep_results_carry_their_resolve_hold():
     assert all(h >= 0 for h in holds)
     # every answer waits for the thresholds served after it
     assert holds[0] > holds[-1]
+    # the engine's answer stamp is turned into the hold, not passed on
+    assert not any("answered_at" in r.service_stats for r in results)
+
+
+def test_stream_query_in_an_append_batch_carries_its_hold():
+    from repro.mining.service import MiningService
+
+    rng = np.random.default_rng(10)
+    spec = MineSpec(algorithm="hprepost", max_k=4, candidate_unit=8, min_sup=0.3)
+    with MiningService(batch_window_s=0.2) as svc:
+        svc.append(random_db(rng, 40, 10, 6), 10, spec=spec).result(timeout=120)
+        fa = svc.append(random_db(rng, 40, 10, 6), 10, spec=spec)
+        fq = svc.submit_stream(spec)
+        fa2 = svc.append(random_db(rng, 40, 10, 6), 10, spec=spec)
+        query = fq.result(timeout=120)
+        appended = [fa.result(timeout=120), fa2.result(timeout=120)]
+    st = query.service_stats
+    assert st["batch_size"] == 3 and st["hold_s"] > 0
+    assert "answered_at" not in st
+    assert all("hold_s" not in a and a["queue_time_s"] >= 0 for a in appended)
 
 
 def test_wave_slots_count_the_padded_slots_of_every_wave():
