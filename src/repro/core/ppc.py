@@ -12,9 +12,11 @@ vectorize, so we construct the *identical* tree algebraically:
    OR of per-column inequality).
 3. Flattening the boundary mask row-major enumerates nodes sorted by
    ``(start_row, depth)`` — which *is* pre-order (DFS of sorted rows).
-4. ``subtree_size`` via ``searchsorted`` on the (non-decreasing) node start
-   rows, and the closed form ``post = pre + size - 1 - depth`` replaces the
-   post-order traversal.
+4. A node's subtree is the nodes after it that start before its end row
+   ``end``. Pre-order numbers are row-major over the boundary mask, so
+   ``pre + size`` is the number of nodes in rows ``0..end-1``: a prefix sum
+   of per-row node counts read at ``end``, no search. The closed form
+   ``post = pre + size - 1 - depth`` replaces the post-order traversal.
 5. ``count`` = windowed sum of row weights over the node's row range.
 
 The result is bit-identical to the pointer-built tree (property-tested
@@ -156,9 +158,11 @@ def build_ppc_jnp(rows: jnp.ndarray, weights: jnp.ndarray, max_nodes: int, n_ite
     item = jnp.where(node_valid, srows[start, depth], -1)
 
     pre = jnp.arange(max_nodes)
-    # invalid slots must sort AFTER every valid start for searchsorted
-    start_key = jnp.where(node_valid, start, R)
-    size = jnp.searchsorted(start_key, end, side="left") - pre
+    # rowcum[r]: nodes starting in rows before r, capped at the slots kept.
+    # Pre-order is row-major over ``newgrp``, so rowcum[end] = pre + size.
+    per_row = newgrp.sum(axis=1, dtype=jnp.int32)
+    rowcum = jnp.concatenate([jnp.zeros(1, jnp.int32), jnp.cumsum(per_row)])
+    size = jnp.minimum(rowcum, max_nodes)[end] - pre
     post = jnp.where(node_valid, pre + size - 1 - depth, jnp.iinfo(jnp.int32).max)
     pre = jnp.where(node_valid, pre, jnp.iinfo(jnp.int32).max)
     return item, count, pre, post, node_valid

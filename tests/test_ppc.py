@@ -1,4 +1,7 @@
-"""PPC-tree construction: paper example + sort-based vs pointer oracle."""
+"""PPC-tree construction: paper example, sort-based vs pointer oracle, and
+the jit-able Job 2 builder on the inputs Job 2 sees."""
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -8,6 +11,8 @@ from hypothesis import given, settings, strategies as st
 from repro.core import encoding as enc
 from repro.core.ppc import _build_ppc_pointer, build_ppc, build_ppc_jnp
 from repro.data.synth import random_db
+
+INT32_MAX = np.iinfo(np.int32).max
 
 
 def _ranked(rows, n_items, min_count):
@@ -76,6 +81,63 @@ def test_jnp_build_matches_numpy(n_tx, n_items, seed):
     np.testing.assert_array_equal(np.asarray(count)[:n], ref.count)
     np.testing.assert_array_equal(np.asarray(pre)[:n], ref.pre)
     np.testing.assert_array_equal(np.asarray(post)[:n], ref.post)
+
+
+def _job2_rows(rng, n_tx, n_items, max_len, min_count, dup, pad_rows):
+    """Rank-encoded rows as Job 2 gets them: not deduped, PAD tails, plus
+    ``dup`` repeated rows and ``pad_rows`` all-PAD rows, shuffled in."""
+    rows = random_db(rng, n_tx, n_items, max_len)
+    fl = enc.build_flist(enc.item_support(rows, n_items), min_count)
+    ranked = enc.rank_encode(rows, fl)
+    extra = [ranked[rng.integers(0, n_tx, dup)], np.full((pad_rows, max_len), enc.PAD, np.int32)]
+    ranked = np.concatenate([ranked, *extra])
+    return ranked[rng.permutation(len(ranked))], fl.k
+
+
+# (n_tx, n_items, max_len, min_count, dup, pad_rows, packed, zero_weights)
+JOB2_CASES = {
+    "duplicate_rows": (40, 10, 6, 1, 40, 0, False, False),
+    "all_pad_rows": (30, 12, 5, 1, 0, 20, False, False),
+    "packed_even_L": (50, 30, 10, 2, 10, 5, True, False),
+    "packed_odd_L": (50, 30, 11, 2, 10, 5, True, False),
+    "mostly_invalid_slots": (60, 40, 24, 15, 0, 60, True, False),
+    "zero_weights": (40, 10, 6, 1, 20, 5, False, True),
+}
+
+
+@pytest.mark.parametrize("case", list(JOB2_CASES))
+def test_jnp_build_matches_pointer_on_job2_inputs(case, rng):
+    n_tx, n_items, max_len, min_count, dup, pad_rows, packed, zero_w = JOB2_CASES[case]
+    rows, k = _job2_rows(rng, n_tx, n_items, max_len, min_count, dup, pad_rows)
+    w = rng.integers(0, 3, len(rows)).astype(np.int32) if zero_w else np.ones(len(rows), np.int32)
+    if zero_w:
+        assert (w == 0).any()
+    max_nodes = rows.size  # Job 2's R·L
+    out = build_ppc_jnp(jnp.asarray(rows), jnp.asarray(w), max_nodes, n_items=k if packed else 0)
+    item, count, pre, post, valid = map(np.asarray, out)
+
+    ref = _build_ppc_pointer(rows, w)
+    n = ref.n_nodes
+    assert 0 < n < max_nodes
+    np.testing.assert_array_equal(valid, np.arange(max_nodes) < n)
+    for name, got in (("item", item), ("count", count), ("pre", pre), ("post", post)):
+        np.testing.assert_array_equal(got[:n], getattr(ref, name), err_msg=name)
+    # invalid slots hold the sentinels the pack stage relies on
+    assert (item[n:] == -1).all() and (count[n:] == 0).all()
+    assert (pre[n:] == INT32_MAX).all() and (post[n:] == INT32_MAX).all()
+    if case == "mostly_invalid_slots":
+        assert n < max_nodes // 4
+
+
+def test_jnp_build_has_no_loop():
+    """Subtree sizes come from a prefix sum, not a search: the lowered Job 2
+    builder at a stream segment's width holds no ``while`` loop."""
+    rows = jnp.zeros((64, 23), jnp.int32)
+    w = jnp.ones(64, jnp.int32)
+    build = jax.jit(build_ppc_jnp, static_argnames=("max_nodes", "n_items"))
+    text = build.lower(rows, w, max_nodes=64 * 23, n_items=119).as_text()
+    assert "stablehlo.sort" in text  # lowered the packed lexsort, not a stub
+    assert "stablehlo.while" not in text
 
 
 def test_subtree_interval_invariants(rng):
