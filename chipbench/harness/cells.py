@@ -7,23 +7,33 @@ every metric; the files are found by those names alone:
     chipbench/traffic/<traffic>.json    the traffic mix (client.py reads it)
     chipbench/metrics/<metric>.py       the metric's reader: read(run)
 
-So a new cell is new files plus a ``workloads`` entry, and no edit.
+So a new cell is new files plus a ``workloads`` entry, and no edit. A
+configuration's ``name`` names its dataset (it seeds the generator, see
+``data.dataset_rng``), so a deployment of the same dataset on another mesh
+keeps it. The service's mesh is the configuration's ``"mesh": [data,
+model]``, or ``(chips, 1)`` where it states none; its size must equal the
+workload's ``chips`` and, where the configuration states them, its
+``chips``. The key ``rehearsal`` is for the CPU rehearsals alone
+(``tests/tinyroot.py``); a run never reads it.
 """
 from __future__ import annotations
 
 import dataclasses
 import importlib.util
 import json
+import math
 import pathlib
 
 BENCH_DIR = pathlib.Path(__file__).resolve().parents[1]
 CHECKOUT = BENCH_DIR.parent
+MESH_AXES = ("data", "model")
 
 
 @dataclasses.dataclass
 class Cell:
     name: str
     chips: int
+    mesh: tuple[int, int]  # the service's (data, model) mesh
     config: dict
     traffic: dict
     end_to_end: list[dict]  # BENCHMARK.json metric entries this cell reports
@@ -38,6 +48,19 @@ def _applies(metric: dict, cell: str) -> bool:
     return "workloads" not in metric or cell in metric["workloads"]
 
 
+def _mesh(name: str, chips: int, config: dict) -> tuple[int, int]:
+    """The service's (data, model) mesh: the configuration's ``mesh``, else
+    ``(chips, 1)``; its size must be the workload's and the configuration's
+    ``chips``."""
+    shape = tuple(int(n) for n in config.get("mesh", (chips, 1)))
+    stated = int(config.get("chips", chips))
+    if len(shape) != len(MESH_AXES) or {math.prod(shape)} != {chips, stated}:
+        raise ValueError(
+            f"{name}: the mesh {list(shape)} over {MESH_AXES} does not fit the "
+            f"workload's {chips} chips and the configuration's {stated}")
+    return shape
+
+
 def find_cell(name: str, bench: dict, bench_dir: pathlib.Path = BENCH_DIR) -> Cell:
     cells = {w["name"]: w for w in bench["workloads"]}
     if name not in cells:
@@ -45,8 +68,10 @@ def find_cell(name: str, bench: dict, bench_dir: pathlib.Path = BENCH_DIR) -> Ce
     w = cells[name]
     config = json.loads((bench_dir / "configs" / f"{w['config']}.json").read_text())
     traffic = json.loads((bench_dir / "traffic" / f"{w['traffic']}.json").read_text())
+    chips = int(w["chips"])
     return Cell(
-        name=name, chips=int(w["chips"]), config=config, traffic=traffic,
+        name=name, chips=chips, mesh=_mesh(name, chips, config), config=config,
+        traffic=traffic,
         end_to_end=[m for m in bench["end_to_end"] if _applies(m, name)],
         per_layer=[m for m in bench["per_layer"] if _applies(m, name)],
     )

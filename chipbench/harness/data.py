@@ -20,7 +20,9 @@ PAD = -1
 
 
 def dataset_rng(cfg: dict) -> np.random.Generator:
-    """The fixed random stream of a configuration's base database."""
+    """The fixed random stream of a configuration's base database, keyed by
+    its ``name`` and data seed: configurations that deploy one dataset on
+    different meshes keep its name, and mine the same rows."""
     seed = int(cfg["assumed"]["data_seed"])
     return np.random.default_rng(seed + zlib.crc32(cfg["name"].encode()) % 2**16)
 

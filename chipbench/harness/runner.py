@@ -2,7 +2,8 @@
 
 ``main`` is what ``chipbench/run.py`` calls. In order it: checks for the
 chip (no TPU, or fewer chips than the cell asks for: exit 2 and no
-result), turns on the compile cache, makes the cell's data from the seed
+result), builds the cell's mesh (``cells.Cell.mesh``), turns on the
+compile cache, makes the cell's data from the seed
 and warms up the cell's own shapes (``setup_s`` ends at the first timed
 request), drives the window through ``MiningService`` (with ``--trace 1``
 under the profiler and with the program's spans recorded), reads the
@@ -54,23 +55,18 @@ def use_compile_cache(root) -> str:
     return path
 
 
-def device_info(chips: int) -> dict:
-    import jax
-
-    devs = jax.devices()[:chips]
+def device_info(devs) -> dict:
     return {"platform": devs[0].platform, "kind": devs[0].device_kind,
             "count": len(devs)}
 
 
-def peak_bytes(chips: int) -> int | None:
+def peak_bytes(devs) -> int | None:
     """The fullest device's peak: ``peak_bytes_in_use`` (the buffers the
     runtime allocates) plus ``peak_bytes_reserved`` (the TPU runtime holds
     the programs' temporaries there, apart from the buffers, and keeps
     them reserved once a program has run)."""
-    import jax
-
     peaks = []
-    for d in jax.devices()[:chips]:
+    for d in devs:
         stats = d.memory_stats() or {}
         if "peak_bytes_in_use" in stats:
             peaks.append(stats["peak_bytes_in_use"] + stats.get("peak_bytes_reserved", 0))
@@ -156,7 +152,7 @@ def main(workload: str, seed: int, seconds: float, trace: bool, *,
     cell = cells.find_cell(workload, bench, root / "chipbench")
     import jax
 
-    dev = device_info(cell.chips)
+    dev = device_info(jax.devices()[:cell.chips])
     t_jax = time.perf_counter() - t_process
     log(f"device: {dev}")
     if require_tpu and dev["platform"] != "tpu":
@@ -165,10 +161,13 @@ def main(workload: str, seed: int, seconds: float, trace: bool, *,
     if len(jax.devices()) < cell.chips:
         log(f"chipbench: {workload} needs {cell.chips} chips, found {len(jax.devices())}")
         return 2
+    from repro.launch.mesh import make_mesh
+
+    mesh = make_mesh(cell.mesh, cells.MESH_AXES)
+    log(f"mesh: {dict(mesh.shape)} over devices {[d.id for d in mesh.devices.flat]}")
     log(f"compile cache: {use_compile_cache(root)}")
 
     from chipbench.harness import tracing
-    from repro.launch.mesh import make_mesh
     from repro.mining.service import MiningService
     from repro.mining.telemetry import trace as program_trace
 
@@ -176,7 +175,7 @@ def main(workload: str, seed: int, seconds: float, trace: bool, *,
     t0 = time.perf_counter()
     base = data.base_database(cell.config)
     t_data = time.perf_counter() - t0
-    service = MiningService(mesh=make_mesh((1, 1), ("data", "model")))
+    service = MiningService(mesh=mesh)
     client = ClosedLoopClient(service, cell.config, cell.traffic, seed, base)
     spans = profiler = None
     try:
@@ -203,9 +202,9 @@ def main(workload: str, seed: int, seconds: float, trace: bool, *,
         gc_pauses.close()
         if trace:
             profiler.stop()
-        peak = peak_bytes(cell.chips)
+        peak = peak_bytes(list(mesh.devices.flat))
         log(f"memory_stats of the first device after the window: "
-            f"{jax.devices()[0].memory_stats()}")
+            f"{mesh.devices.flat[0].memory_stats()}")
     finally:
         compiles.close()
         if profiler is not None:

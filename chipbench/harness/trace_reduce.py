@@ -12,11 +12,25 @@ only host events the reduction reads. Both sides share the profiler's
 clock.
 
 The reduction measures inside the window (the ``bench:window``
-annotation): device busy time is the union of the op intervals, averaged
-over the devices; an idle gap is an interval of the window in which no op
-ran on a device, attributed to the innermost benchmark or program span the
-host had open at the gap's midpoint; a kernel's time is the sum of the
-durations of the ops whose own name names it.
+annotation), over the device planes that ran an op in it (one per chip the
+cell uses):
+
+- device busy time is, on each plane, the union of its op intervals,
+  averaged over the planes;
+- an idle gap is an interval of the window in which a plane ran no op; each
+  plane's gaps go to the innermost benchmark or program span the host had
+  open at the gap's midpoint, and the breakdown divides the sums by the
+  number of planes, so its idle time is the window less ``busy_ns``, as the
+  average chip sees it (a chip that waits on a collective while the others
+  work shows its wait);
+- collective time is, on each plane, the union of its collective ops'
+  intervals, averaged over the planes;
+- a kernel's time is the sum of the durations of the ops whose own name
+  names it, over all planes: chip-seconds. Least bytes over one chip's
+  bandwidth, divided by chip-seconds, equals least bytes over the planes'
+  aggregate bandwidth, divided by the kernel's time per chip.
+
+On one plane, averaging changes nothing.
 """
 from __future__ import annotations
 
@@ -32,6 +46,12 @@ from chipbench.harness.tracing import HOST_PREFIX, WINDOW_ANNOTATION as WINDOW
 OP_LINES = ("XLA Ops", "Async XLA Ops")
 MODULE_LINE = "XLA Modules"  # one event per program execution
 _OPCODE = re.compile(r" ([a-z][\w\-]*)\(")
+# the opcodes of the exchanges between chips, each also in its async
+# -start/-done form
+COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "collective-permute",
+               "all-to-all")
+_COLLECTIVE_OPCODES = frozenset(
+    c + suffix for c in COLLECTIVES for suffix in ("", "-start", "-done"))
 
 
 def op_name(hlo: str) -> str:
@@ -95,24 +115,43 @@ def _clip(s: int, e: int, w0: int, w1: int):
     return (s, e) if e > s else None
 
 
-def busy_ns(trace: dict) -> tuple[float, int]:
-    """(device busy ns averaged over the devices, window ns)."""
+def _plane_busy(trace: dict, keep=lambda ev: True) -> dict[str, list]:
+    """For each plane that ran an op in the window, the union of the
+    intervals of its ops that ``keep`` selects, clipped to the window."""
     w0, w1 = window_ns(trace)
     per_plane: dict[str, list] = defaultdict(list)
-    for plane, _, _, s, d, _ in device_ops(trace):
-        iv = _clip(s, s + d, w0, w1)
+    for ev in device_ops(trace):
+        iv = _clip(ev[3], ev[3] + ev[4], w0, w1)
         if iv:
-            per_plane[plane].append(iv)
+            kept = per_plane[ev[0]]  # the plane counts once it ran an op
+            if keep(ev):
+                kept.append(iv)
+    return {plane: _union_ns(iv) for plane, iv in per_plane.items()}
+
+
+def _mean_over_planes(per_plane: dict[str, list]) -> float:
     if not per_plane:
-        return 0.0, w1 - w0
-    total = sum(sum(e - s for s, e in _union_ns(iv)) for iv in per_plane.values())
-    return total / len(per_plane), w1 - w0
+        return 0.0
+    return sum(sum(e - s for s, e in iv) for iv in per_plane.values()) / len(per_plane)
+
+
+def busy_ns(trace: dict) -> tuple[float, int]:
+    """(device busy ns averaged over the planes, window ns)."""
+    w0, w1 = window_ns(trace)
+    return _mean_over_planes(_plane_busy(trace)), w1 - w0
+
+
+def collective_ns(trace: dict) -> float:
+    """Device time of the collective ops (``COLLECTIVES`` by their recorded
+    opcode), in the window, averaged over the planes."""
+    return _mean_over_planes(_plane_busy(
+        trace, lambda ev: ev[5].get("opcode") in _COLLECTIVE_OPCODES))
 
 
 def kernel_ns(trace: dict, kernel_names) -> int:
     """Summed device time of the ops whose own name starts with one of
     ``kernel_names`` (a Pallas call's op is named after the function that
-    makes it), inside the window, over all devices."""
+    makes it), inside the window, over all planes: chip-seconds."""
     w0, w1 = window_ns(trace)
     total = 0
     for _, _, name, s, d, _ in device_ops(trace):
@@ -123,19 +162,21 @@ def kernel_ns(trace: dict, kernel_names) -> int:
     return total
 
 
-def idle_gaps(trace: dict) -> list[tuple[int, int]]:
-    """Intervals of the window in which no device ran an op."""
+def idle_gaps(trace: dict) -> dict[str, list[tuple[int, int]]]:
+    """For each plane that ran an op in the window, the intervals of the
+    window in which it ran none."""
     w0, w1 = window_ns(trace)
-    busy = _union_ns([iv for _, _, _, s, d, _ in device_ops(trace)
-                      if (iv := _clip(s, s + d, w0, w1))])
-    gaps, t = [], w0
-    for s, e in busy:
-        if s > t:
-            gaps.append((t, s))
-        t = max(t, e)
-    if w1 > t:
-        gaps.append((t, w1))
-    return gaps
+    out = {}
+    for plane, busy in _plane_busy(trace).items():
+        gaps, t = [], w0
+        for s, e in busy:
+            if s > t:
+                gaps.append((t, s))
+            t = max(t, e)
+        if w1 > t:
+            gaps.append((t, w1))
+        out[plane] = gaps
+    return out
 
 
 def host_activity(trace: dict, times) -> list[str]:
@@ -179,9 +220,9 @@ def _module_of(trace: dict):
 
 
 def breakdown(trace: dict, top: int = 10) -> dict:
-    """The contract's breakdown: device ops (``program/op``) by summed
-    time, and idle time by what the host was doing, each the ``top``
-    largest, in seconds."""
+    """The contract's breakdown: device ops (``program/op``) by time
+    summed over the planes, and idle time by what the host was doing,
+    averaged over the planes, each the ``top`` largest, in seconds."""
     w0, w1 = window_ns(trace)
     module = _module_of(trace)
     by_op: dict[str, int] = defaultdict(int)
@@ -190,10 +231,12 @@ def breakdown(trace: dict, top: int = 10) -> dict:
         if iv and line == OP_LINES[0]:
             by_op[f"{module(plane, s)}/{name}"] += iv[1] - iv[0]
     by_host: dict[str, int] = defaultdict(int)
-    gaps = idle_gaps(trace)
-    for (s, e), who in zip(gaps, host_activity(trace, [(s + e) / 2 for s, e in gaps])):
-        by_host[who] += e - s
+    per_plane = idle_gaps(trace)
+    for gaps in per_plane.values():
+        for (s, e), who in zip(gaps, host_activity(trace, [(s + e) / 2 for s, e in gaps])):
+            by_host[who] += e - s
     ops = sorted(by_op.items(), key=lambda kv: -kv[1])[:top]
     gaps = sorted(by_host.items(), key=lambda kv: -kv[1])[:top]
+    planes = len(per_plane)
     return {"device_ops": [[n, v / 1e9] for n, v in ops],
-            "idle_gaps": [[n, v / 1e9] for n, v in gaps]}
+            "idle_gaps": [[n, v / planes / 1e9] for n, v in gaps]}

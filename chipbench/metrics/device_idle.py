@@ -1,5 +1,6 @@
 """device_idle (%): share of the traced window in which no op ran on the
-device: 100 * (1 - union of the device op intervals / window)."""
+device: 100 * (1 - busy / window), busy the union of a device's op
+intervals, averaged over the devices (harness/trace_reduce.py)."""
 from chipbench.harness import trace_reduce
 
 

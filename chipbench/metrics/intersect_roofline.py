@@ -1,7 +1,12 @@
 """intersect_roofline (%): the least time any exact implementation needs
 for the window's N-list intersections (the work model's bytes over the
 chip's HBM bandwidth; see harness/workmodel.py) as a share of the device
-time of the intersect kernel's ops in the trace."""
+time of the intersect kernel's ops in the trace.
+
+The kernel's time is summed over the chips (chip-seconds, see
+harness/trace_reduce.py), so on several chips the share is the least bytes
+over one chip's bandwidth divided by chip-seconds: the least bytes over
+the chips' aggregate bandwidth divided by the kernel's time per chip."""
 from chipbench.harness import peaks, trace_reduce
 
 # the Pallas calls of kernels/nlist_intersect carry the names of the

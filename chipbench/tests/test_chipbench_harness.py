@@ -2,6 +2,7 @@
 import io
 import json
 import os
+import shutil
 import subprocess
 import sys
 import time
@@ -10,7 +11,7 @@ import numpy as np
 import pytest
 
 from chipbench.harness import cells, data, reference, runner
-from chipbench.tests import tinyroot
+from chipbench.tests import rehearse, tinyroot
 
 CELLS = [w["name"] for w in cells.load_benchmark()["workloads"]]
 
@@ -27,6 +28,13 @@ def no_persistent_cache(monkeypatch):
 
 
 def run_cell(root, name, *, trace=False, seed=2**31 + 7):
+    """One rehearsal of a cell: in this process on its one CPU device, or,
+    for a cell on more chips, in a child with as many (``rehearse``)."""
+    chips = cells.find_cell(name, cells.load_benchmark(root), root / "chipbench").chips
+    if chips > 1:
+        p = rehearse.run(root, name, seed, trace, chips)
+        assert p.returncode == 0, p.stderr[-4000:]
+        return json.loads(p.stdout.strip().splitlines()[-1])
     out = io.StringIO()
     rc = runner.main(name, seed, 0.2, trace, t_process=time.perf_counter(),
                      require_tpu=False, root=root, out=out)
@@ -34,10 +42,8 @@ def run_cell(root, name, *, trace=False, seed=2**31 + 7):
     return json.loads(out.getvalue().strip().splitlines()[-1])
 
 
-@pytest.mark.parametrize("trace", [False, True], ids=["trace0", "trace1"])
-@pytest.mark.parametrize("name", CELLS)
-def test_cell_runs_and_is_correct(root, name, trace):
-    res = run_cell(root, name, trace=trace)
+def check_result(root, name, trace, res):
+    """What every cell's rehearsal must show."""
     assert res["correct"] is True
     assert res["attempted"] > 0 and res["failed"] == 0
     assert list(res)[-1] == "checks"
@@ -52,9 +58,20 @@ def test_cell_runs_and_is_correct(root, name, trace):
         if m["name"] in res["metrics"]:
             assert res["metrics"][m["name"]]["unit"] == m["unit"]
     assert res["device"]["platform"] == "cpu"
+    assert res["device"]["count"] == cell.chips
     if trace:
         assert set(res["breakdown"]) == {"device_ops", "idle_gaps"}
         assert res["device"]["window_s"] > 0
+
+
+@pytest.mark.parametrize("trace", [False, True], ids=["trace0", "trace1"])
+@pytest.mark.parametrize("name", CELLS)
+def test_cell_runs_and_is_correct(root, name, trace):
+    check_result(root, name, trace, run_cell(root, name, trace=trace))
+
+
+SMALL_SWEEP = {"warmup": [{"op": "mine", "database": "resident", "min_sups": [0.3, 0.2], "max_k": 4}],
+               "cycle": [{"op": "mine", "database": "resident", "min_sups": [0.3, 0.2], "max_k": 4}]}
 
 
 def test_a_new_cell_is_new_files_and_a_workloads_entry(root, tmp_path):
@@ -62,15 +79,98 @@ def test_a_new_cell_is_new_files_and_a_workloads_entry(root, tmp_path):
     cfg = json.loads((new / "chipbench/configs/mushroom.json").read_text())
     cfg.update(name="mushroom_small", n_rows=300)
     (new / "chipbench/configs/mushroom_small.json").write_text(json.dumps(cfg))
-    (new / "chipbench/traffic/resident_sweep_0.3-0.2.json").write_text(json.dumps({
-        "warmup": [{"op": "mine", "database": "resident", "min_sups": [0.3, 0.2], "max_k": 4}],
-        "cycle": [{"op": "mine", "database": "resident", "min_sups": [0.3, 0.2], "max_k": 4}]}))
+    (new / "chipbench/traffic/resident_sweep_0.3-0.2.json").write_text(json.dumps(SMALL_SWEEP))
     bench = json.loads((new / "BENCHMARK.json").read_text())
     bench["workloads"].append({"name": "mushroom_small.sweep", "config": "mushroom_small",
                                "traffic": "resident_sweep_0.3-0.2", "chips": 1, "why": "t"})
     (new / "BENCHMARK.json").write_text(json.dumps(bench))
     res = run_cell(new, "mushroom_small.sweep")
     assert res["correct"] is True and res["attempted"] % 2 == 0
+
+
+def source_checkout(tmp, configs=(), traffic=(), workloads=()):
+    """This tree's BENCHMARK.json and chipbench data files under ``tmp``,
+    with new configurations and traffic mixes ({name: dict}) and
+    ``workloads`` entries added: a checkout that differs from this one only
+    by new files and entries."""
+    tmp.mkdir(parents=True, exist_ok=True)
+    shutil.copy(tinyroot.ROOT / "BENCHMARK.json", tmp / "BENCHMARK.json")
+    for sub in ("configs", "traffic", "metrics"):
+        shutil.copytree(tinyroot.BENCH / sub, tmp / "chipbench" / sub)
+    for sub, files in (("configs", configs), ("traffic", traffic)):
+        for name, content in dict(files).items():
+            (tmp / "chipbench" / sub / f"{name}.json").write_text(json.dumps(content))
+    bench = json.loads((tmp / "BENCHMARK.json").read_text())
+    bench["workloads"] += list(workloads)
+    (tmp / "BENCHMARK.json").write_text(json.dumps(bench))
+    return tmp
+
+
+def test_a_new_configuration_is_cut_by_the_rehearsal_rule(tmp_path):
+    cfg = json.loads((tinyroot.BENCH / "configs/mushroom.json").read_text())
+    del cfg["rehearsal"]
+    cfg["name"] = "mushroom_wide"
+    src = source_checkout(
+        tmp_path / "src", configs={"mushroom_wide": cfg},
+        traffic={"resident_sweep_0.3-0.2": SMALL_SWEEP},
+        workloads=[{"name": "mushroom_wide.sweep", "config": "mushroom_wide",
+                    "traffic": "resident_sweep_0.3-0.2", "chips": 1, "why": "t"}])
+    new = tinyroot.make(tmp_path / "tiny", src)
+    rows = {p.stem: json.loads(p.read_text())["n_rows"]
+            for p in (new / "chipbench/configs").glob("*.json")}
+    assert rows == {"kosarak": 3000, "mushroom": 600, "mushroom_wide": tinyroot.MAX_ROWS}
+    stream = json.loads((new / "chipbench/traffic/window3_every16_sup0.13.json").read_text())
+    assert stream["stream"]["batch_rows"] == 200
+    assert {s["count"] for s in stream["cycle"] if s["op"] == "append"} == {tinyroot.TINY_APPENDS}
+    check_result(new, "mushroom_wide.sweep", False, run_cell(new, "mushroom_wide.sweep"))
+
+
+X4 = "kosarak.oneshot.x4"
+
+
+@pytest.fixture(scope="module")
+def x4_root(tmp_path_factory):
+    """A checkout with one new configuration (kosarak on a 4x1 mesh of four
+    chips) and one four-chip ``workloads`` entry, cut for rehearsal."""
+    cfg = json.loads((tinyroot.BENCH / "configs/kosarak.json").read_text())
+    cfg.update(chips=4, mesh=[4, 1])
+    tmp = tmp_path_factory.mktemp("x4")
+    src = source_checkout(tmp / "src", configs={"kosarak_x4": cfg}, workloads=[
+        {"name": X4, "config": "kosarak_x4", "traffic": "fresh_db_sup0.01", "chips": 4,
+         "why": "t"}])
+    return tinyroot.make(tmp / "tiny", src)
+
+
+@pytest.mark.parametrize("trace", [False, True], ids=["trace0", "trace1"])
+def test_a_four_chip_cell_is_new_files_and_a_workloads_entry(x4_root, trace):
+    check_result(x4_root, X4, trace, run_cell(x4_root, X4, trace=trace))
+
+
+def test_a_four_chip_rehearsal_serves_from_four_data_shards(x4_root):
+    p = rehearse.run(x4_root, X4, 2**31 + 17, False, 4)
+    assert p.returncode == 0, p.stderr[-4000:]
+    check_result(x4_root, X4, False, json.loads(p.stdout.strip().splitlines()[-1]))
+    assert "mesh: {'data': 4, 'model': 1}" in p.stderr
+    shards = [json.loads(line.split(":", 1)[1]) for line in p.stderr.splitlines()
+              if line.startswith("data shards:")]
+    assert shards, p.stderr[-4000:]
+    for counts in shards:  # every prepared database: four shards, none empty
+        assert len(counts) == 4 and min(counts) > 0
+
+
+@pytest.mark.parametrize("mesh,chips", [([2, 1], 4), ([4, 1], 1), ([4], 4)],
+                         ids=["smaller_than_the_cell", "not_the_configurations", "one_axis"])
+def test_a_mesh_that_does_not_fit_the_chips_is_an_error(tmp_path, mesh, chips):
+    cfg = json.loads((tinyroot.BENCH / "configs/mushroom.json").read_text())
+    cfg.update(chips=chips, mesh=mesh)
+    root = source_checkout(tmp_path, configs={"mushroom_mesh": cfg}, workloads=[
+        {"name": "mushroom_mesh.sweep", "config": "mushroom_mesh",
+         "traffic": "resident_sweep_0.20-0.13", "chips": 4, "why": "t"}])
+    out = io.StringIO()
+    with pytest.raises(ValueError, match="mesh"):
+        runner.main("mushroom_mesh.sweep", 3, 0.2, False, t_process=time.perf_counter(),
+                    require_tpu=False, root=root, out=out)
+    assert out.getvalue() == ""
 
 
 def test_unknown_cell_is_an_error(root):

@@ -39,7 +39,7 @@ def test_union_window_and_gaps_by_hand():
     busy, window = tr.busy_ns(t)
     assert window == 1000
     assert busy == 80 + 100 + 50
-    assert tr.idle_gaps(t) == [(0, 100), (180, 300), (400, 950)]
+    assert tr.idle_gaps(t) == {D: [(0, 100), (180, 300), (400, 950)]}
 
 
 def test_kernel_lookup_by_name():
@@ -66,6 +66,53 @@ def test_gap_attribution_by_hand():
     assert ["?/fusion.9", 50e-9] in b["device_ops"]  # ran in no recorded program
 
 
+def _two_plane_trace():
+    D1 = "/device:TPU:1"
+    ops = [
+        [D, "XLA Ops", "fusion.1", 100, 80, {"opcode": "fusion"}],          # 100-180
+        [D, "XLA Ops", "all-reduce.1", 300, 100, {"opcode": "all-reduce"}],  # 300-400
+        [D1, "XLA Ops", "fusion.1", 0, 600, {"opcode": "fusion"}],          # 0-800 with
+        [D1, "Async XLA Ops", "all-reduce-start.2", 600, 20,               # the next
+         {"opcode": "all-reduce-start"}],
+        [D1, "Async XLA Ops", "all-reduce-done.2", 610, 90,
+         {"opcode": "all-reduce-done"}],
+        [D1, "XLA Ops", "all-reduce-fusion.3", 700, 100, {"opcode": "fusion"}],  # no collective
+        [D1, "XLA Ops", "all-gather.4", 850, 50, {"opcode": "all-gather"}],  # 850-900
+    ]
+    host = [
+        ["python", "bench:window", 0, 1000],
+        ["python", "bench:group.serve", 0, 1000],
+        ["python", "bench:mine.reduce", 180, 120],
+        ["worker", "bench:client.make_database", 400, 400],
+    ]
+    return {"device": ops, "host": host}
+
+
+def test_two_planes_are_reduced_per_plane():
+    # plane 0 is busy 180 ns, plane 1 850 ns; the union of both planes
+    # (800 + 50 ns) would hide plane 0's waits
+    t = _two_plane_trace()
+    assert tr.busy_ns(t) == ((180 + 850) / 2, 1000)
+    assert tr.idle_gaps(t) == {D: [(0, 100), (180, 300), (400, 1000)],
+                               "/device:TPU:1": [(800, 850), (900, 1000)]}
+    # gaps by the span open at their midpoints, summed, over two planes:
+    # make_database 600 (plane 0), mine.reduce 120 (plane 0), group.serve
+    # 100 (plane 0) + 50 + 100 (plane 1); in all the window less busy_ns
+    idle = tr.breakdown(t)["idle_gaps"]
+    assert idle == [["client.make_database", 300e-9], ["group.serve", 125e-9],
+                    ["mine.reduce", 60e-9]]
+    assert sum(v for _, v in idle) == pytest.approx((1000 - tr.busy_ns(t)[0]) * 1e-9)
+    # all-reduce 100 on plane 0; start and done overlapping, 600-700, and
+    # all-gather 50 on plane 1; the fusion named after a collective is none
+    assert tr.collective_ns(t) == (100 + 150) / 2
+    # a kernel's time is summed over the planes: chip-seconds
+    assert tr.kernel_ns(t, ("fusion",)) == 80 + 600
+
+
+def test_a_trace_without_collectives_reads_none():
+    assert tr.collective_ns(_hand_trace()) == 0
+
+
 @pytest.fixture(scope="module")
 def recorded():
     path = DATA / "trace_v5e.json.gz"
@@ -90,7 +137,8 @@ def test_recorded_trace_union_matches_a_timeline(recorded):
     assert window == w1 - w0
     assert busy == int(line.sum())
     gaps = tr.idle_gaps(recorded)
-    assert sum(e - s for s, e in gaps) == int((~line).sum())
+    assert list(gaps) == [D]
+    assert sum(e - s for s, e in gaps[D]) == int((~line).sum())
 
 
 def test_recorded_trace_names_the_intersect_kernel(recorded):
@@ -102,3 +150,25 @@ def test_recorded_trace_names_the_intersect_kernel(recorded):
     b = tr.breakdown(recorded)
     assert b["device_ops"] and b["idle_gaps"]
     assert all(isinstance(n, str) and v > 0 for n, v in b["device_ops"] + b["idle_gaps"])
+
+
+def test_recorded_trace_reads_as_before_on_one_plane(recorded):
+    # the values the union over all planes gave before the reduction went
+    # per plane: on one plane they are the same
+    assert tr.busy_ns(recorded) == (90861427.0, 120000000)
+    gaps = tr.idle_gaps(recorded)[D]
+    assert len(gaps) == 74 and sum(e - s for s, e in gaps) == 29138573
+    assert gaps[:2] == [(3621075003, 3621075005), (3621489558, 3621507882)]
+    assert gaps[-2:] == [(3672387859, 3672387861), (3672413140, 3672413145)]
+    assert tr.breakdown(recorded) == {
+        "device_ops": [
+            ["jit_wave_local/nlist_intersect_pallas_es.2", 0.070251087],
+            ["jit_wave/nlist_intersect_pallas_es.2", 0.019374109],
+            ["jit_wave_local/convert_bitcast_fusion", 0.0004248],
+            ["jit_wave/fusion.3", 0.000112893], ["jit_wave_local/fusion.3", 8.9463e-05],
+            ["jit_wave/fusion.1", 8.8232e-05], ["jit_wave/fusion", 8.8199e-05],
+            ["jit_wave_local/fusion.2", 6.2569e-05], ["jit_wave_local/fusion.1", 6.1837e-05],
+            ["jit_wave_local/fusion.5", 2.3829e-05]],
+        "idle_gaps": [["client.mine", 0.026550266], ["group.serve", 0.002557304],
+                      ["mine.reduce", 3.0971e-05], ["mine.wave", 3.2e-08]]}
+    assert tr.collective_ns(recorded) == 0
