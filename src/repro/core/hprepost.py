@@ -124,7 +124,9 @@ class PreparedDB:
     C: np.ndarray  # (K, K) upper-triangular F2 co-occurrence counts
     prep_bytes: int  # per-shard footprint: sharded rows + F-list + packed
     rows_flist_bytes: int  # the threshold-independent part of prep_bytes
-    stage_times: dict[str, float]  # job1_flist / job2_ppc_pack / f2_scan
+    stage_times: dict[str, float]  # job1_flist / job2_ppc_pack / f2_scan,
+    # and exchange_bytes, the payload each chip adds to the reductions
+    # over `data` (0 on one data shard)
     f1_only: bool = False  # True when built with need_waves=False
     n_shards: int = 1  # data-shard count (D) this prep was laid out for
     # False when the F-list order was imposed externally (``prepare(...,
@@ -133,6 +135,14 @@ class PreparedDB:
     # segment building blocks for ``mine_prepared_segments``; the prefix
     # arithmetic ``mine_prepared`` leans on does not hold for them.
     support_ordered: bool = True
+    # N-list nodes summed over shards and items: the valid share of the
+    # ``nlist_slots`` that every wave's kernel reads
+    nlist_nodes: int = 0
+
+    @property
+    def nlist_slots(self) -> int:
+        """The packed N-list slots, D x K x W (0 when F1-only)."""
+        return 0 if self.packed is None else self.n_shards * self.fl.k * self.width
 
     def to_host(self) -> dict:
         """Gather the prep to a host payload (plain numpy + scalars) for
@@ -192,6 +202,7 @@ class PreparedDB:
             if C.shape != (fl.k, fl.k):
                 raise ValueError(f"snapshot C has shape {C.shape}, expected {(fl.k, fl.k)}")
             packed = singleton = None
+            nodes = 0
             if not f1_only and fl.k > 0:
                 ph = np.asarray(payload["packed"], np.int32)
                 want = (n_shards, fl.k, width, 3)
@@ -199,6 +210,7 @@ class PreparedDB:
                     raise ValueError(f"snapshot packed has shape {ph.shape}, expected {want}")
                 packed = miner._shard(ph, P(miner._da, None, None, None))
                 singleton = packed[:, :, :, 2]
+                nodes = int(np.count_nonzero(ph[..., 0] != INF32))  # valid slots
         except (KeyError, TypeError, OverflowError) as e:
             raise ValueError(f"malformed PreparedDB snapshot payload: {e!r}") from e
         return cls(
@@ -217,6 +229,7 @@ class PreparedDB:
             n_shards=n_shards,
             # pre-PR5 snapshots carry no key: they were all support-ordered
             support_ordered=bool(payload.get("support_ordered", True)),
+            nlist_nodes=nodes,
         )
 
     def bytes_at(self, min_count: int, n_shards: int) -> int:
@@ -354,6 +367,15 @@ class LocalSegmentExecutor:
         return np.sum(stacked, axis=0, dtype=np.int64)
 
 
+def fold_prep_stages(stages: dict[str, float], prep_stages: dict[str, float]) -> None:
+    """Fold the stage times of the prep a request paid for into the
+    request's own: the prep's times replace the zeroed keys, and its
+    exchange adds to the waves'."""
+    waves_exchange = stages.get("exchange_bytes", 0.0)
+    stages.update(prep_stages)
+    stages["exchange_bytes"] = waves_exchange + prep_stages.get("exchange_bytes", 0.0)
+
+
 def _pow2(n: int) -> int:
     p = 1
     while p < n:
@@ -457,14 +479,15 @@ class HPrepostMiner:
                 lens = jax.ops.segment_sum(
                     jnp.where(valid, 1, 0), jnp.where(valid, item, k), num_segments=k + 1
                 )[:k]
-                lens = jax.lax.pmax(lens, da)
-                return ranked[None], item[None], count[None], pre[None], post[None], lens
+                # each shard's N-list lengths go to the host, which takes
+                # the max for W and the sum for the node count
+                return ranked[None], item[None], count[None], pre[None], post[None], lens[None]
 
             return shard_map(
                 functools.partial(body, lut=lut),
                 mesh=mesh,
                 in_specs=P(da, None),
-                out_specs=(P(da, None), P(da), P(da), P(da), P(da), P()),
+                out_specs=(P(da, None), P(da), P(da), P(da), P(da), P(da, None)),
             )(rows)
 
         @functools.partial(jax.jit, static_argnames=("k", "width"))
@@ -606,8 +629,12 @@ class HPrepostMiner:
         through ``mine_prepared_segments``."""
         cfg = self.cfg
         stages: dict[str, float] = {}
+        # payload each chip adds to the reductions over `data`, from shapes
+        exchange = 0
         t0 = time.perf_counter()
-        with trace.span("prep.job1"):
+        with trace.span("prep.split"):
+            # the data split: rows padded to a multiple of D, each shard's
+            # block placed on its chip; the span ends when they are there
             R0, L = rows.shape
             Rp = (R0 + self.D - 1) // self.D * self.D
             # the Pallas intersect kernel accumulates counts in fp32 (exact
@@ -626,10 +653,13 @@ class HPrepostMiner:
                 )
             rows_p = np.full((Rp, L), enc.PAD, np.int32)
             rows_p[:R0] = rows
-            rows_sharded = self._shard(rows_p, P(self._da, None))
+            rows_sharded = jax.block_until_ready(self._shard(rows_p, P(self._da, None)))
 
+        with trace.span("prep.job1"):
             if flist is None:
-                supports = np.asarray(jax.device_get(self._job1(rows_sharded, n_items=n_items)))
+                hist = self._job1(rows_sharded, n_items=n_items)
+                supports = np.asarray(jax.device_get(hist))
+                exchange += hist.nbytes
                 self.stage_counters["job1"] += 1
                 fl = enc.build_flist(supports, min_count_floor)
             else:
@@ -650,7 +680,7 @@ class HPrepostMiner:
         stages["f2_scan"] = 0.0
         packed = singleton = None
         C = np.zeros((K, K), np.int64)
-        W = 0
+        W = nodes = 0
         if K > 0 and need_waves:
             # each stage span ends when its device work is done: Job 2 at
             # the device_get of its N-list lengths, pack at an explicit
@@ -663,7 +693,9 @@ class HPrepostMiner:
                     n_items=n_items,
                 )
                 self.stage_counters["job2"] += 1
-                w_needed = int(np.asarray(jax.device_get(lens)).max(initial=1))
+                lens = np.asarray(jax.device_get(lens))  # (D, K)
+                w_needed = int(lens.max(initial=1))
+                nodes = int(lens.sum())
             W = cfg.nlist_width or _pow2(max(w_needed, 8))
             with trace.span("prep.pack"):
                 packed = jax.block_until_ready(self._pack(item, count, pre, post, k=K, width=W))
@@ -673,7 +705,9 @@ class HPrepostMiner:
             t0 = time.perf_counter()
             with trace.span("prep.f2"):
                 if K > 1:
-                    C = np.asarray(jax.device_get(self._jobf2(ranked, k=K)))
+                    cooc = self._jobf2(ranked, k=K)
+                    C = np.asarray(jax.device_get(cooc))
+                    exchange += cooc.nbytes
                     self.stage_counters["f2"] += 1
                 C = np.triu(C, 1)
             stages["f2_scan"] = time.perf_counter() - t0
@@ -682,13 +716,14 @@ class HPrepostMiner:
             # counts (replicated over `model`: the bootstrap take is
             # collective-free)
             singleton = packed[:, :, :, 2]
+        stages["exchange_bytes"] = float(exchange if self.D > 1 else 0)
 
         return PreparedDB(
             fl=fl, n_items=n_items, n_rows=R0, min_count_floor=int(min_count_floor),
             width=W, packed=packed, singleton_state=singleton, C=C,
             prep_bytes=prep_bytes, rows_flist_bytes=rows_flist_bytes,
             stage_times=stages, f1_only=not need_waves, n_shards=self.D,
-            support_ordered=flist is None,
+            support_ordered=flist is None, nlist_nodes=nodes,
         )
 
     def _pack_wave(self, ranks, parents, qarr, level: int, slots_per_shard: int):
@@ -835,6 +870,11 @@ class HPrepostMiner:
             # slots of all waves (what the kernel computes)
             "planned_candidates": 0.0, "largest_wave": 0.0, "wave_slots": 0.0,
             "host_pruned_parent": 0.0, "host_pruned_subset": 0.0,
+            # the N-lists the waves read (the prep's, cached or not), and
+            # the payload each chip adds to the waves' support psums
+            "nlist_nodes": float(prepared.nlist_nodes),
+            "nlist_slots": float(prepared.nlist_slots),
+            "exchange_bytes": 0.0,
         }
         itemsets: dict[tuple[int, ...], int] = {}
         k_act = prepared.k_active(min_count)
@@ -906,6 +946,8 @@ class HPrepostMiner:
                         early_stop=plan.early_stop,
                     )
                 self.stage_counters["waves"] += 1
+                if self.D > 1:
+                    stages["exchange_bytes"] += float(Cpad // Mb * sups.dtype.itemsize)
                 if stop_count and plan.early_stop and is_pallas(plan.backend):
                     self.stage_counters["stop_waves"] += 1
                 dispatched = (ranks, parents, slot_of, sups)
@@ -1213,5 +1255,5 @@ class HPrepostMiner:
         )
         res = self.mine_prepared(prepared, min_count, max_k=max_k)
         # one-shot path pays its own prep: fold the real stage times back in
-        self.last_stage_times.update(prepared.stage_times)
+        fold_prep_stages(self.last_stage_times, prepared.stage_times)
         return res

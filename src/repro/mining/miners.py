@@ -265,10 +265,12 @@ class HPrepostFrontend(_MinerBase):
         min_count = spec.resolve(prepared.n_rows)
         if t0 is None:
             t0 = time.perf_counter()
+        from repro.core.hprepost import fold_prep_stages
+
         res = miner.mine_prepared(prepared, min_count, max_k=spec.max_k)
         stages = dict(miner.last_stage_times)
         if prep_stages:
-            stages.update(prep_stages)
+            fold_prep_stages(stages, prep_stages)
         return self._finish(
             res.itemsets, res.total_count, res.n_explicit, res.peak_bytes,
             stages, res.flist_items,

@@ -21,7 +21,9 @@ span tree per request:
                                   segments; the host prune is outside)
       resolve                    (retroactive)
 
-    prep.job1                    (padding, device_put, Job 1 to its device_get)
+    prep.split                   (rows padded to a multiple of D, each shard's
+                                  block placed on its chip, to a sync)
+    prep.job1                    (Job 1 to its device_get)
     prep.job2                    (Job 2 to the device_get of its N-list lengths)
     prep.pack                    (pack to its block_until_ready)
     prep.f2                      (F2 to its device_get)

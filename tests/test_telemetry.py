@@ -373,7 +373,7 @@ def test_distributed_mine_records_per_worker_wave_histograms():
 
 
 # ------------------------------------------ prep stages, batching, hold
-PREP_SPANS = ["prep.job1", "prep.job2", "prep.pack", "prep.f2"]
+PREP_SPANS = ["prep.split", "prep.job1", "prep.job2", "prep.pack", "prep.f2"]
 
 
 def test_prepare_records_stage_spans_that_sum_to_the_stage_times():
